@@ -89,6 +89,7 @@ from repro.dist.amb import AMBConfig, num_workers           # noqa: E402
 from repro.dist.params import tree_shardings                # noqa: E402
 from repro.kernels import ref                               # noqa: E402
 from repro.kernels.gossip_combine import gossip_combine_pallas  # noqa: E402
+from repro.launch.mesh import make_mesh                     # noqa: E402
 from repro.models import init_params                        # noqa: E402
 from repro.optim import make_optimizer                      # noqa: E402
 
@@ -107,7 +108,7 @@ def _time_it(fn, *args, iters: int = 5) -> float:
 
 
 def bench_train_steps(arch: str, steps: int, seq_len: int) -> dict:
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = smoke_config(arch)
     n = num_workers(mesh)
     beta = BetaSchedule(k=20.0, mu=1.0, scale=50.0)
@@ -291,7 +292,7 @@ def bench_pipelined(arch: str, steps: int, seq_len: int,
                                 seq_weights_from_b, strategy_from_config,
                                 unpack_duals)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = smoke_config(arch)
     n = num_workers(mesh)
     per = 2
@@ -740,7 +741,7 @@ def bench_churn(arch: str, steps: int, seq_len: int,
     # representative churned mask (non-adjacent failures: the mask the
     # dense induced-subgraph operator cannot even express on a ring)
     mask = (True, True, False, True, True, False, True, True)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sh = NamedSharding(mesh, P("data"))
     msgs = jax.device_put(
         jax.random.normal(jax.random.PRNGKey(0), (8, 1 << 16)), sh)
@@ -909,7 +910,7 @@ def bench_multipod(arch: str, seq_len: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.dist_step", "--multipod-probe",
          "--arch", arch, "--seq-len", str(seq_len)],

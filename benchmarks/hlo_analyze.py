@@ -49,8 +49,6 @@ def analyze(arch: str, shape_name: str, layers: int, top: int,
         lowered = D._lower_combo(cfg, shape, mesh)
         compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jaxlib: one dict per device
-        ca = ca[0] if ca else {}
     print(f"== {arch} x {shape_name} (layers={layers}) "
           f"mesh={'2x16x16' if multi_pod else '16x16'}")
     print(f"flops/chip={ca.get('flops', 0):.4g}  "

@@ -27,6 +27,7 @@ from repro.data import LMTokenStream, shard_batch            # noqa: E402
 from repro.dist import use_sharding                          # noqa: E402
 from repro.dist.amb import AMBConfig, make_train_step, num_workers  # noqa: E402
 from repro.dist.params import tree_shardings                 # noqa: E402
+from repro.launch.mesh import make_mesh                      # noqa: E402
 from repro.metrics import MetricsLogger                      # noqa: E402
 from repro.models import init_params, param_count            # noqa: E402
 from repro.models.common import ArchConfig                   # noqa: E402
@@ -57,7 +58,7 @@ def main():
     ndev = len(jax.devices())
     data = 4 if ndev >= 8 else max(1, ndev)
     model = 2 if ndev >= 8 else 1
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model), ("data", "model"))
     n = num_workers(mesh)
     gb = n * args.batch_per_worker
 

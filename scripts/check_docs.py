@@ -9,8 +9,8 @@ fails if any points at something that does not exist:
     included), and a trailing ``.symbol`` must appear as a word in that
     module's source;
   * **CLI flags** — every ``--flag`` mention must be declared by some
-    ``add_argument("--flag" ...)`` under ``src/``, ``benchmarks/`` or
-    ``examples/`` (underscore flags like XLA's are exempt — they are
+    ``add_argument("--flag" ...)`` under ``src/``, ``benchmarks/``,
+    ``examples/`` or in ``chip_smoke.py`` (underscore flags like XLA's are exempt — they are
     not argparse surface);
   * **local paths** — markdown links and backtick-quoted paths (with a
     ``/`` and a known extension) must exist on disk.
@@ -46,6 +46,7 @@ def declared_flags() -> set[str]:
     for base in (SRC, ROOT / "benchmarks", ROOT / "examples"):
         for py in base.rglob("*.py"):
             flags.update(ADD_ARG_RE.findall(py.read_text()))
+    flags.update(ADD_ARG_RE.findall((ROOT / "chip_smoke.py").read_text()))
     for sh in (ROOT / "scripts").glob("*.sh"):   # verify.sh case labels
         flags.update(SH_FLAG_RE.findall(sh.read_text()))
     return flags
@@ -98,8 +99,8 @@ def check() -> int:
                 continue
             if flag not in flags:
                 errors.append(f"{rel}: CLI flag {flag} is not declared by "
-                              f"any add_argument in src/, benchmarks/ or "
-                              f"examples/")
+                              f"any add_argument in src/, benchmarks/, "
+                              f"examples/ or chip_smoke.py")
         refs = set(LINK_RE.findall(text)) | set(PATH_RE.findall(text))
         for ref in sorted(refs):
             if "<" in ref:             # placeholder paths like step_<n>/

@@ -29,6 +29,7 @@ import jax                                   # noqa: E402
 from repro.api import (AMBSession, ClockSpec, ConsensusSpec,  # noqa: E402
                        TrainSpec)
 from repro.kernels import router             # noqa: E402
+from repro.launch.mesh import make_mesh     # noqa: E402
 from repro.models.common import ArchConfig   # noqa: E402
 
 
@@ -37,7 +38,7 @@ def _session():
                      num_heads=2, num_kv_heads=2, head_dim=16, d_ff=64,
                      vocab_size=64, q_chunk=16, kv_chunk=16,
                      mxu_f32_accum=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return AMBSession(TrainSpec(batch_per_worker=2, seq_len=8),
                       ClockSpec(kind="simulated"), ConsensusSpec(),
                       mesh=mesh, cfg=cfg)

@@ -14,16 +14,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=cpu
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
-# NB: the persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR)
-# is deliberately NOT enabled here.  On this container's jaxlib (0.4.36,
-# CPU) the cache's serializable-executable compile path mishandles
-# input-output aliasing for the session's donated train-step
-# executables: tests/test_async.py::test_restore_roundtrip_tiny goes
-# NaN and glibc reports heap corruption ("corrupted size vs.
-# prev_size") with the cache on, and is clean with it off — or with
-# donation off.  Donation is the win we keep; re-enable the cache only
-# after a jaxlib upgrade proves this combination clean.
-unset JAX_COMPILATION_CACHE_DIR
 
 pytest_args=()
 smoke=1

@@ -77,25 +77,26 @@ class LMTokenStream:
     seed: int = 0
     num_blocks: int = 16
 
-    def _transition_logits(self) -> Array:
+    def _transition_logits(self, tok: Array) -> Array:
+        """Row ``tok`` of the (v, v) transition table, drawn on demand: a
+        published vocabulary (152k) would need v^2 floats (92 GB) up front."""
         v = self.vocab_size
-        key = jax.random.PRNGKey(self.seed ^ 0x70CE)
-        base = jax.random.normal(key, (v, v), jnp.float32) * 0.5
+        key = jax.random.fold_in(jax.random.PRNGKey(self.seed ^ 0x70CE), tok)
+        base = jax.random.normal(key, (v,), jnp.float32) * 0.5
         blk = v // self.num_blocks or 1
-        same = (jnp.arange(v)[:, None] // blk) == (jnp.arange(v)[None] // blk)
+        same = (jnp.arange(v) // blk) == (tok // blk)
         return base + 2.0 * same
 
     def batch(self, node: int, epoch: int, size: int) -> dict:
         key = jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(self.seed), node), epoch)
-        logits = self._transition_logits()
 
         def seq(k):
             k0, ks = jax.random.split(k)
             first = jax.random.randint(k0, (), 0, self.vocab_size)
 
             def step(tok, kk):
-                nxt = jax.random.categorical(kk, logits[tok])
+                nxt = jax.random.categorical(kk, self._transition_logits(tok))
                 return nxt, nxt
 
             _, rest = jax.lax.scan(step, first,

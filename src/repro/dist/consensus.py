@@ -49,8 +49,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import PartitionSpec as P
+
 from ..core import consensus as cns
 from ..kernels import ops as kops
+from .sharding import active_mesh
 
 Array = jax.Array
 
@@ -293,6 +296,15 @@ def _mask_rows(out: Array, orig: Array, active) -> Array:
     return jnp.where(mask, out, orig)
 
 
+def _row_spec(ndim: int) -> P:
+    """Layout of a worker-row stack ``(n, ...)`` on the ambient mesh: rows
+    over the worker axes (every axis but "model")."""
+    mesh = active_mesh()
+    waxes = () if mesh is None else tuple(
+        a for a in mesh.axis_names if a != "model")
+    return P(waxes or None, *([None] * (ndim - 1)))
+
+
 def _roll_taps(m: Array, taps) -> Array:
     """Stack the neighbor views: (K, n, ...) from (n, ...)."""
     return jnp.stack([taps.take(m, i) for i in range(taps.k)])
@@ -425,12 +437,11 @@ class GossipConsensus(_TapGossip):
             return cns.gossip(m, jnp.asarray(self.p, jnp.float32),
                               self.rounds)
         w = jnp.asarray(self.taps.weights)
+        spec = _row_spec(m.ndim)
 
         def one_round(_, cur):
-            stacked = _roll_taps(cur, self.taps)
-            out = kops.gossip_combine(
-                stacked.reshape(self.taps.k, -1), w)
-            return out.reshape(cur.shape)
+            return kops.gossip_combine(_roll_taps(cur, self.taps), w,
+                                       spec=spec)
 
         out = jax.lax.fori_loop(0, self.rounds, one_round, m)
         # survivor relayout: inactive workers keep their rows (identity);
@@ -507,6 +518,7 @@ class QuantizedGossipConsensus(_TapGossip):
         d = m.shape[1]
         w = jnp.asarray(taps.weights)
         km1 = taps.k - 1
+        spec = _row_spec(m.ndim)
 
         def one_round(k_round, carry):
             cur, h, hnbr = carry
@@ -523,7 +535,7 @@ class QuantizedGossipConsensus(_TapGossip):
                 rnd = jax.random.uniform(jax.random.fold_in(key, k_round),
                                          cur.shape)
             lvl, h_new = kops.stochastic_quantize(cur, h, rnd, lo, scale,
-                                                  levels)
+                                                  levels, spec=spec)
             # -- the wire: rolled (nibble-packed) level planes + scalars.
             # The barriers pin the collective-permute to the uint8 plane:
             # without them XLA hoists the u8->f32 dequant (and the 4-bit
@@ -540,7 +552,7 @@ class QuantizedGossipConsensus(_TapGossip):
                               for j in range(1, taps.k)])
             # -- receive half: fused dequantize + replica update + combine
             out, hnbr_new = kops.quantized_combine(
-                cur, hnbr, lvl_r, lo_r, sc_r, w)
+                cur, hnbr, lvl_r, lo_r, sc_r, w, spec=spec)
             return out, h_new, hnbr_new
 
         h0 = jnp.zeros_like(m)

@@ -43,37 +43,36 @@ LANE = 128
 def _kernel(msgs_ref, w_ref, o_ref, *, k: int):
     acc = jnp.zeros(o_ref.shape, jnp.float32)
     for j in range(k):
-        acc = acc + w_ref[0, j] * msgs_ref[j, :, :].astype(jnp.float32)
+        acc = acc + w_ref[0, j] * msgs_ref[j:j + 1, :].astype(jnp.float32)
     o_ref[...] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def gossip_combine_pallas(msgs: Array, weights: Array, *,
-                          block_rows: int = 512,
+                          block: int = 512 * LANE,
                           interpret: bool = False) -> Array:
-    """msgs: (K, N); weights: (K,). Returns (N,) fp32."""
-    k, n = msgs.shape
-    pad = (-n) % LANE
-    m = jnp.pad(msgs, ((0, 0), (0, pad)))
-    rows = m.shape[1] // LANE
-    m = m.reshape(k, rows, LANE)
-    grid = -(-rows // block_rows)
-    row_pad = grid * block_rows - rows
-    m = jnp.pad(m, ((0, 0), (0, row_pad), (0, 0)))
-    w2 = weights.astype(jnp.float32).reshape(1, k)
+    """msgs: (K, N); weights: (K,). Returns (N,) fp32.
 
+    Tiles the message axis in place: the last block runs past N and
+    Pallas masks its writes, so no padded copy of the model-sized
+    messages is made (padding an N that is not a multiple of 128 and
+    reshaping it also makes the TPU compile time grow with N).
+    """
+    k, n = msgs.shape
+    block = min(block, n)       # a block wider than N must equal N
+    w2 = weights.astype(jnp.float32).reshape(1, k)
     out = pl.pallas_call(
         functools.partial(_kernel, k=k),
-        grid=(grid,),
+        grid=(pl.cdiv(n, block),),
         in_specs=[
-            pl.BlockSpec((k, block_rows, LANE), lambda i: (0, i, 0)),
+            pl.BlockSpec((k, block), lambda i: (0, i)),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m.shape[1], LANE), jnp.float32),
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
-    )(m, w2)
-    return out.reshape(-1)[:n]
+    )(msgs, w2)
+    return out.reshape(n)
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +102,8 @@ def _squantize_kernel(m_ref, h_ref, rnd_ref, lo_ref, scale_ref,
     # clamp: the row max can round to u = levels + eps; an up-round there
     # would emit 2^bits, which wraps past the top of the uint8 wire plane
     lvl = jnp.minimum(lvl, levels)
-    lvl_ref[...] = lvl.astype(jnp.uint8)
+    # Mosaic casts float <-> uint8 only by way of int32
+    lvl_ref[...] = lvl.astype(jnp.int32).astype(jnp.uint8)
     hnew_ref[...] = h_ref[...].astype(jnp.float32) + lo + lvl * scale
 
 
@@ -162,7 +162,8 @@ def _qcombine_kernel(m_ref, hnbr_ref, lvl_ref, lo_ref, scale_ref, w_ref,
     for j in range(k - 1):
         h = (hnbr_ref[j].astype(jnp.float32)
              + lo_ref[j, 0, 0]
-             + lvl_ref[j].astype(jnp.float32) * scale_ref[j, 0, 0])
+             + lvl_ref[j].astype(jnp.int32).astype(jnp.float32)
+             * scale_ref[j, 0, 0])
         hnbr_new_ref[j] = h
         acc = acc + w_ref[0, j + 1] * h
     out_ref[...] = acc

@@ -6,6 +6,12 @@ debugging oracle, never a silent production path), overridable globally
 via ``REPRO_KERNELS`` / ``TrainSpec.kernels`` and per call via ``force``
 (the test suite's oracle sweeps).  The routing decision is made at trace
 time and logged once by the router.
+
+XLA cannot partition a Mosaic (Pallas TPU) kernel.  Under an ambient mesh
+of more than one device (:func:`repro.dist.sharding.use_sharding`) each
+kernel therefore runs inside ``shard_map`` on the local blocks; ``spec``
+names the layout of the caller's array (one parameter leaf, or one
+worker-row stack ``(n, ...)``) on that mesh.
 """
 from __future__ import annotations
 
@@ -13,7 +19,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ..dist.sharding import active_mesh
 from . import ref, router
 from .dual_update import dual_update_pallas
 from .flash_attention import flash_attention_pallas
@@ -24,17 +32,36 @@ from .rwkv6_scan import rwkv6_scan_pallas
 Array = jax.Array
 
 
+def _per_device(fn, in_specs, out_specs):
+    """``fn`` on each device's blocks under a multi-device ambient mesh."""
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _stacked(spec: P) -> P:
+    """The spec of ``(K,) + x.shape`` for an ``x`` laid out as ``spec``."""
+    return P(None, *spec)
+
+
 def dual_update(z: Array, w0: Array, beta: Array,
                 radius: Optional[float] = None,
-                force: Optional[str] = None) -> Array:
-    """w = w0 - z/(2 beta), optionally projected onto ||w - w0|| <= radius."""
+                force: Optional[str] = None, spec: P = P()) -> Array:
+    """w = w0 - z/(2 beta), optionally projected onto ||w - w0|| <= radius.
+
+    ``spec``: the layout of ``z`` and ``w0`` on the ambient mesh.
+    """
     impl = router.resolve(force)
-    if impl == "pallas_interpret":
-        w = dual_update_pallas(z, w0, beta, interpret=True)
-    elif impl == "ref":
+    if impl == "ref":
         w = ref.dual_update_ref(z, w0, beta)
     else:
-        w = dual_update_pallas(z, w0, beta)
+        interpret = impl == "pallas_interpret"
+        w = _per_device(
+            lambda z, w0, beta: dual_update_pallas(z, w0, beta,
+                                                   interpret=interpret),
+            (spec, spec, P()), spec)(z, w0, beta)
     if radius is not None:
         delta = w - w0.astype(jnp.float32)
         nrm = jnp.linalg.norm(delta.reshape(-1))
@@ -44,40 +71,57 @@ def dual_update(z: Array, w0: Array, beta: Array,
 
 
 def gossip_combine(msgs: Array, weights: Array,
-                   force: Optional[str] = None) -> Array:
-    """K-way weighted combine of stacked neighbor messages: (K, N) -> (N,)."""
+                   force: Optional[str] = None, spec: P = P()) -> Array:
+    """K-way weighted combine of stacked neighbor messages: (K, ...) -> (...).
+
+    ``spec``: the layout of one message ``msgs[k]`` on the ambient mesh.
+    """
     impl = router.resolve(force)
-    if impl == "pallas_interpret":
-        return gossip_combine_pallas(msgs, weights, interpret=True)
     if impl == "ref":
         return ref.gossip_combine_ref(msgs, weights)
-    return gossip_combine_pallas(msgs, weights)
+    interpret = impl == "pallas_interpret"
+
+    def local(m, w):
+        out = gossip_combine_pallas(m.reshape(m.shape[0], -1), w,
+                                    interpret=interpret)
+        return out.reshape(m.shape[1:])
+
+    return _per_device(local, (_stacked(spec), P()), spec)(msgs, weights)
 
 
 def stochastic_quantize(m: Array, h: Array, rnd: Array, lo: Array,
                         scale: Array, levels: float = 255.0,
-                        force: Optional[str] = None):
-    """Send half of a quantized gossip round: (levels u8, updated replica)."""
+                        force: Optional[str] = None, spec: P = P()):
+    """Send half of a quantized gossip round: (levels u8, updated replica).
+
+    ``spec``: the layout of the ``(n, ...)`` worker-row arrays.
+    """
     impl = router.resolve(force)
-    if impl == "pallas_interpret":
-        return stochastic_quantize_pallas(m, h, rnd, lo, scale,
-                                          levels=levels, interpret=True)
     if impl == "ref":
         return ref.stochastic_quantize_ref(m, h, rnd, lo, scale, levels)
-    return stochastic_quantize_pallas(m, h, rnd, lo, scale, levels=levels)
+    interpret = impl == "pallas_interpret"
+    return _per_device(
+        lambda *a: stochastic_quantize_pallas(*a, levels=levels,
+                                              interpret=interpret),
+        (spec,) * 5, (spec, spec))(m, h, rnd, lo, scale)
 
 
 def quantized_combine(m: Array, hnbr: Array, lvl: Array, lo: Array,
                       scale: Array, weights: Array,
-                      force: Optional[str] = None):
-    """Receive half: fused dequantize + replica update + K-way combine."""
+                      force: Optional[str] = None, spec: P = P()):
+    """Receive half: fused dequantize + replica update + K-way combine.
+
+    ``spec``: the layout of the ``(n, ...)`` worker-row arrays.
+    """
     impl = router.resolve(force)
-    if impl == "pallas_interpret":
-        return quantized_combine_pallas(m, hnbr, lvl, lo, scale, weights,
-                                        interpret=True)
     if impl == "ref":
         return ref.quantized_combine_ref(m, hnbr, lvl, lo, scale, weights)
-    return quantized_combine_pallas(m, hnbr, lvl, lo, scale, weights)
+    interpret = impl == "pallas_interpret"
+    taps = _stacked(spec)
+    return _per_device(
+        lambda *a: quantized_combine_pallas(*a, interpret=interpret),
+        (spec, taps, taps, taps, taps, P()), (spec, taps))(
+            m, hnbr, lvl, lo, scale, weights)
 
 
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,

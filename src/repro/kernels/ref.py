@@ -21,10 +21,10 @@ def dual_update_ref(z: Array, w0: Array, beta: Array) -> Array:
 def gossip_combine_ref(msgs: Array, weights: Array) -> Array:
     """Weighted neighbor combine: out = sum_k weights[k] * msgs[k].
 
-    msgs: (K, N); weights: (K,).  This is one row of m <- P m restricted to
-    the K in-neighborhood messages (self included).
+    msgs: (K, ...); weights: (K,).  This is one row of m <- P m restricted
+    to the K in-neighborhood messages (self included).
     """
-    return jnp.einsum("k,kn->n", weights.astype(jnp.float32),
+    return jnp.einsum("k,k...->...", weights.astype(jnp.float32),
                       msgs.astype(jnp.float32))
 
 

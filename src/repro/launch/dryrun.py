@@ -27,7 +27,7 @@ from ..dist.params import tree_shardings                     # noqa: E402
 from ..models import decode_step, prefill                    # noqa: E402
 from ..optim import DualAveragingOpt                         # noqa: E402
 from . import specs as S                                     # noqa: E402
-from .mesh import make_production_mesh                       # noqa: E402
+from .mesh import make_mesh, make_production_mesh           # noqa: E402
 
 # v5e constants for §Roofline
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
@@ -141,8 +141,6 @@ def _lower_combo(cfg, shape, mesh):
 
 def _costs(compiled) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jaxlib: one dict per device
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
             "collectives": parse_collectives(compiled.as_text())}
@@ -204,7 +202,7 @@ def _mesh(multi_pod: bool):
     if override:
         dims = tuple(int(x) for x in override.split(","))
         axes = ("pod", "data", "model")[-len(dims):]
-        return jax.make_mesh(dims, axes)
+        return make_mesh(dims, axes)
     return make_production_mesh(multi_pod=multi_pod)
 
 

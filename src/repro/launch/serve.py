@@ -29,6 +29,7 @@ from ..api import AMBSession, ClockSpec, ConsensusSpec, TrainSpec
 from ..serve import (AdmissionPolicy, RequestQueue, SamplingSpec,
                      ServeMetrics, ServeScheduler, SlotEngine,
                      synthetic_requests)
+from .cache import use_compile_cache
 
 
 def main(argv=None):
@@ -67,6 +68,7 @@ def main(argv=None):
     ap.add_argument("--metrics", default=None, metavar="PATH",
                     help="JSONL path for SLO + fine-tune metrics")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     train = TrainSpec(arch=args.arch, smoke=args.smoke,
                       seq_len=args.finetune_seq_len,

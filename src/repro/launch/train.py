@@ -37,6 +37,7 @@ import argparse
 
 from ..api import (AMBSession, ClockSpec, ConsensusSpec, ControllerSpec,
                    TrainSpec)
+from .cache import use_compile_cache
 
 
 def main(argv=None):
@@ -64,6 +65,7 @@ def main(argv=None):
     ap.add_argument("--churn-seed", type=int, default=0,
                     help="fault-trajectory seed (independent of --seed)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     faults = None
     if args.churn > 0.0:

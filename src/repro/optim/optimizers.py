@@ -46,15 +46,19 @@ class DualAveragingOpt(Optimizer):
         }
 
     def apply(self, grads, state, params):
+        from ..dist.params import ambient_spec
         from ..kernels import ops as kops
         t_new = state["t"] + 1
         beta = self.beta(t_new.astype(jnp.float32) + 1.0)
         z_new = jax.tree.map(
             lambda z, g: z + g.astype(jnp.float32), state["z"], grads)
-        def prox(z, w0, p):
-            w = kops.dual_update(z, w0, beta, self.radius)
+
+        def prox(path, z, w0, p):
+            w = kops.dual_update(z, w0, beta, self.radius,
+                                 spec=ambient_spec(path, p.shape))
             return w.astype(p.dtype)
-        new_params = jax.tree.map(prox, z_new, state["w0"], params)
+        new_params = jax.tree_util.tree_map_with_path(
+            prox, z_new, state["w0"], params)
         return new_params, {"z": z_new, "w0": state["w0"], "t": t_new}
 
 

@@ -1,7 +1,4 @@
 import os
-import random
-import sys
-import types
 
 # Tests run on the single real CPU device; ONLY subprocess-based distribution
 # tests force a device count (never set globally here, per the dry-run
@@ -11,90 +8,3 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
-
-
-# ---------------------------------------------------------------------------
-# hypothesis shim: this container cannot pip-install, so when hypothesis is
-# absent we register a minimal API-compatible stand-in (seeded random
-# sampling, `max_examples` draws per test) under the same module name BEFORE
-# test modules are collected.  Property tests keep running — with less
-# adversarial example search — instead of failing at import.
-# ---------------------------------------------------------------------------
-
-def _install_hypothesis_shim():
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example_with(self, rng):
-            return self._draw(rng)
-
-    def integers(min_value=0, max_value=1 << 31):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def floats(min_value=0.0, max_value=1.0, **_):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def booleans():
-        return _Strategy(lambda rng: bool(rng.getrandbits(1)))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda rng: rng.choice(elements))
-
-    def just(value):
-        return _Strategy(lambda rng: value)
-
-    def lists(elem, min_size=0, max_size=10):
-        return _Strategy(lambda rng: [
-            elem.example_with(rng)
-            for _ in range(rng.randint(min_size, max_size))])
-
-    def tuples(*elems):
-        return _Strategy(
-            lambda rng: tuple(e.example_with(rng) for e in elems))
-
-    def given(*gargs, **gkwargs):
-        def deco(fn):
-            def wrapper(*args, **kwargs):
-                n = getattr(fn, "_shim_max_examples", 20)
-                rng = random.Random(0xA3B)
-                for _ in range(n):
-                    ex_args = tuple(s.example_with(rng) for s in gargs)
-                    ex_kwargs = {k: s.example_with(rng)
-                                 for k, s in gkwargs.items()}
-                    fn(*args, *ex_args, **kwargs, **ex_kwargs)
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper._shim_wrapped = fn
-            return wrapper
-        return deco
-
-    def settings(max_examples=20, **_):
-        def deco(fn):
-            # applies below OR above @given; thread through either way
-            target = getattr(fn, "_shim_wrapped", fn)
-            target._shim_max_examples = max_examples
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    st_mod = types.ModuleType("hypothesis.strategies")
-    for name, obj in [("integers", integers), ("floats", floats),
-                      ("booleans", booleans), ("sampled_from", sampled_from),
-                      ("just", just), ("lists", lists), ("tuples", tuples)]:
-        setattr(st_mod, name, obj)
-
-    hyp = types.ModuleType("hypothesis")
-    hyp.given = given
-    hyp.settings = settings
-    hyp.strategies = st_mod
-    hyp.__is_repro_shim__ = True
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_shim()

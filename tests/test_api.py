@@ -18,6 +18,7 @@ import pytest
 from repro.api import (AMBSession, ClockSpec, ConsensusSpec, MeasuredClock,
                        SimulatedClock, TrainSpec, build_protocol, make_clock)
 from repro.core.stragglers import amb_batch_sizes
+from repro.launch.mesh import make_mesh
 
 from test_dist import run_sub      # canonical forced-device subprocess
 
@@ -125,7 +126,7 @@ def _tiny_session(consensus=ConsensusSpec(), clock=None, seed=0):
                      num_heads=2, num_kv_heads=2, head_dim=16, d_ff=64,
                      vocab_size=64, q_chunk=16, kv_chunk=16,
                      mxu_f32_accum=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     train = TrainSpec(batch_per_worker=2, seq_len=8, seed=seed)
     return AMBSession(train, clock or ClockSpec(kind="simulated"),
                       consensus, mesh=mesh, cfg=cfg), cfg
@@ -190,7 +191,7 @@ def test_gossip_rejects_non_dual_averaging():
         AMBSession(TrainSpec(optimizer="adamw"),
                    ClockSpec(kind="simulated"),
                    ConsensusSpec(consensus="gossip"),
-                   mesh=jax.make_mesh((1, 1), ("data", "model")))
+                   mesh=make_mesh((1, 1), ("data", "model")))
     from repro.dist.amb import AMBConfig
     from repro.optim import AdamW
     with pytest.raises(ValueError):

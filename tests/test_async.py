@@ -21,6 +21,7 @@ from repro.core.extensions import run_amb_delayed, run_amb_pipelined
 from repro.core.objectives import LinearRegression
 from repro.core.stragglers import amb_budget_from_fmb
 from repro.dist.amb import AMBConfig
+from repro.launch.mesh import make_mesh
 
 from test_dist import run_sub      # canonical forced-device subprocess
 
@@ -54,7 +55,7 @@ def test_build_protocol_async_dispatch_rules():
                        async_epochs=True)
     with pytest.raises(ValueError):       # queue needs >= 1 slot
         from repro.dist.async_epochs import make_async_gossip_train_step
-        make_async_gossip_train_step(None, jax.make_mesh((1,), ("data",)),
+        make_async_gossip_train_step(None, make_mesh((1,), ("data",)),
                                      AMBConfig(), staleness=0)
 
 
@@ -64,7 +65,7 @@ def test_session_rejects_non_dual_averaging_async():
         AMBSession(TrainSpec(optimizer="adamw"),
                    ClockSpec(kind="simulated"),
                    ConsensusSpec(async_epochs=True),
-                   mesh=jax.make_mesh((1, 1), ("data", "model")))
+                   mesh=make_mesh((1, 1), ("data", "model")))
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,7 @@ from repro.control import (BatchDampingPolicy, BudgetPolicy, ControlAction,
                            Telemetry)
 from repro.core.stragglers import (ShiftedExponential, amb_batch_sizes,
                                    amb_budget_from_fmb)
+from repro.launch.mesh import make_mesh
 
 from test_dist import run_sub      # canonical forced-device subprocess
 
@@ -298,7 +299,7 @@ def _tiny_controlled_session(clock, controller, consensus=None,
                      num_heads=2, num_kv_heads=2, head_dim=16, d_ff=64,
                      vocab_size=64, q_chunk=16, kv_chunk=16,
                      mxu_f32_accum=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     train = TrainSpec(batch_per_worker=2, seq_len=8)
     cons = consensus or ConsensusSpec(consensus="gossip", gossip_rounds=2)
     return AMBSession(train, clock, cons, controller, mesh=mesh,

@@ -27,10 +27,11 @@ from repro.kernels import router
 
 from test_api import _tiny_session
 from repro.api import ConsensusSpec
+from repro.launch.mesh import make_mesh
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +45,8 @@ def test_put_batch_places_leading_dim_on_data_axis():
     dev = put_batch(batch, mesh)
     for leaf in jax.tree.leaves(dev):
         assert isinstance(leaf.sharding, jax.sharding.NamedSharding)
-        assert leaf.sharding.spec[0] == ("data",)
+        # PartitionSpec normalises the one-axis tuple ("data",) to "data"
+        assert leaf.sharding.spec[0] == "data"
     np.testing.assert_array_equal(np.asarray(dev["tokens"]),
                                   batch["tokens"])
 

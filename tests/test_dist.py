@@ -22,7 +22,7 @@ def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          cwd=os.path.dirname(os.path.dirname(__file__)),
@@ -83,8 +83,9 @@ def test_exact_train_step_descends_on_mesh():
         from repro.models import init_params, lm_loss
         from repro.optim import make_optimizer
         from repro.core.dual_averaging import BetaSchedule
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = smoke_config("qwen2-1.5b")
         opt = make_optimizer("adamw", lr=3e-3)
         stream = LMTokenStream(vocab_size=cfg.vocab_size, seq_len=32, seed=0)
@@ -149,8 +150,9 @@ def test_gossip_train_step_on_mesh():
         from repro.models import init_params
         from repro.optim import make_optimizer
         from repro.core.dual_averaging import BetaSchedule
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = smoke_config("qwen2-1.5b")
         beta = BetaSchedule(k=20.0, mu=1.0, scale=50.0)
         stream = LMTokenStream(vocab_size=cfg.vocab_size, seq_len=32, seed=0)
@@ -231,8 +233,9 @@ def test_gossip_train_step_multi_pod():
         from repro.data import LMTokenStream, shard_batch
         from repro.models import init_params
         from repro.core.dual_averaging import BetaSchedule
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = smoke_config("qwen2-1.5b")
         assert num_workers(mesh) == 4
         beta = BetaSchedule(k=20.0, mu=1.0, scale=50.0)

@@ -14,6 +14,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import consensus as cns
 from repro.dist.amb import (num_workers, ring_gossip, ring_p,
                             seq_weights_from_b, worker_axes)
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -115,7 +116,7 @@ def test_exact_step_trivial_mesh_descends():
                      num_heads=2, num_kv_heads=2, head_dim=32, d_ff=128,
                      vocab_size=128, q_chunk=32, kv_chunk=32,
                      mxu_f32_accum=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     stream = LMTokenStream(vocab_size=cfg.vocab_size, seq_len=16, seed=0)
     opt = make_optimizer("adamw", lr=1e-2)
     with use_sharding(mesh):
@@ -147,7 +148,7 @@ def test_gossip_step_zero_batch_preserves_duals():
                      num_heads=2, num_kv_heads=2, head_dim=16, d_ff=64,
                      vocab_size=64, q_chunk=16, kv_chunk=16,
                      mxu_f32_accum=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     amb = AMBConfig(consensus="gossip", gossip_rounds=2,
                     beta=BetaSchedule(k=5.0, mu=1.0, scale=10.0))
     stream = LMTokenStream(vocab_size=cfg.vocab_size, seq_len=8, seed=0)

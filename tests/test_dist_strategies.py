@@ -17,6 +17,7 @@ from repro.core.extensions import gossip_quantized
 from repro.dist.consensus import (ExactConsensus, GossipConsensus,
                                   QuantizedGossipConsensus, group_taps,
                                   make_strategy)
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +171,7 @@ def _tiny_setup():
                      num_heads=2, num_kv_heads=2, head_dim=16, d_ff=64,
                      vocab_size=64, q_chunk=16, kv_chunk=16,
                      mxu_f32_accum=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     beta = BetaSchedule(k=5.0, mu=1.0, scale=10.0)
     stream = LMTokenStream(vocab_size=cfg.vocab_size, seq_len=8, seed=0)
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -241,8 +242,9 @@ def test_pipelined_flush_equivalence_on_mesh():
         from repro.data import LMTokenStream, shard_batch
         from repro.models import init_params
         from repro.core.dual_averaging import BetaSchedule
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = smoke_config("qwen2-1.5b")
         beta = BetaSchedule(k=20.0, mu=1.0, scale=50.0)
         stream = LMTokenStream(vocab_size=cfg.vocab_size, seq_len=32, seed=0)
@@ -289,8 +291,9 @@ def test_torus_gossip_step_trains_on_mesh():
         from repro.data import LMTokenStream, shard_batch
         from repro.models import init_params
         from repro.core.dual_averaging import BetaSchedule
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         assert torus_shape_for_mesh(mesh) == (2, 2)
         cfg = smoke_config("qwen2-1.5b")
         beta = BetaSchedule(k=20.0, mu=1.0, scale=50.0)

@@ -451,8 +451,9 @@ def test_churned_ring_combine_stays_on_permute_fast_path():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.dist import make_strategy
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         active = (True, True, False, True, True, False, True, True)
         for name in ("gossip", "gossip_q8"):
             g = make_strategy(name, 8, rounds=2, graph="ring",
