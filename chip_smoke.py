@@ -17,6 +17,7 @@ each phase prints its numbers on the lines before it.  Data comes from
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -45,13 +46,11 @@ ARCH = "qwen2-1.5b"
 # ~10.5 GiB at 8 layers and ~12.2 GiB at 12 (bf16 weights, f32 dual and
 # anchor, gradients, activations at 8 x 256 tokens).
 LAYERS = 12
-# The four-chip phase checks the cross-chip mechanics.  The ring-gossip
-# step holds each worker's f32 message, its two rolled neighbour copies and
-# their (3, D+1) stack: at one layer and the published 152k vocabulary it
-# needs ~17.5 GiB per chip, so the vocabulary is sliced there (all other
-# widths stay published).
+# The four-chip phase runs the published widths at one layer (514M
+# parameters): the ring-gossip step holds each worker's f32 dual, its
+# message, the two neighbour views and the combined output, ~11.7 GiB per
+# chip as compiled for a described v5e:2x2.
 FOUR_CHIP_LAYERS = 1
-FOUR_CHIP_VOCAB = 8192
 STEPS = 5
 SEQ_LEN = 256
 BATCH_PER_WORKER = 8
@@ -62,6 +61,10 @@ PARAM_TOL = 2.0 ** -7
 # node-averaged gossip primal vs the exact-consensus primal, relative to the
 # parameter scale: one bf16 ulp of the update path.
 PRIMAL_TOL = 2.0 ** -7
+# every worker's dual after a gossip epoch on the routed kernels vs the jnp
+# reference route, |z - z_ref| / (1 + |z_ref|): f32 rounding over the
+# rounds' multiply-adds and the eq.-6 normalisation.
+GOSSIP_TOL = 1e-5
 
 
 class CompileTimer:
@@ -87,6 +90,17 @@ def _log(**kv) -> None:
 def _check(cond: bool, what: str) -> None:
     if not cond:
         raise SystemExit(f"chip_smoke: FAILED: {what}")
+
+
+@contextlib.contextmanager
+def _kernels(mode: str):
+    """Route the kernels traced inside through ``mode``."""
+    prev = router.mode()
+    router.set_mode(mode)
+    try:
+        yield
+    finally:
+        router.set_mode(prev)
 
 
 # ---------------------------------------------------------------------------
@@ -122,6 +136,16 @@ def train_phase(cfg, *, steps: int = STEPS, seq_len: int = SEQ_LEN,
 
     session.run(steps, on_step=on_step)
     return session, out
+
+
+def batch_seconds(session: AMBSession) -> float:
+    """Seconds of one compiled build of an epoch's global batch (the token
+    stream runs on the device, beside the step)."""
+    source = session.batch_source()
+    jax.block_until_ready(source.batch(session.steps_done))
+    t0 = time.perf_counter()
+    jax.block_until_ready(source.batch(session.steps_done + 1))
+    return time.perf_counter() - t0
 
 
 def dual_update_check(session: AMBSession, *, force: str = "pallas") -> dict:
@@ -193,30 +217,62 @@ def _worker_rows(leaf) -> dict:
             for s in leaf.addressable_shards}
 
 
-def four_chip_phase(cfg, *, seq_len: int = 128, batch_per_worker: int = 2,
-                    seed: int = 0) -> dict:
-    """One exact-consensus and one ring-gossip epoch on the same batch over
-    a 4x1 (data, model) mesh, every worker at its full minibatch.
+def _one_epoch(cfg, mesh, train, consensus, batch, b):
+    """An :class:`AMBSession` on ``mesh`` after one epoch on ``batch``."""
+    session = AMBSession(train, ClockSpec(kind="simulated"), consensus,
+                         mesh=mesh, cfg=cfg)
+    return session, session.step(batch, b)
+
+
+def gossip_vs_ref(cfg, mesh, train, consensus, batch, b):
+    """One gossip epoch from the shared init through the routed kernels,
+    and again through the jnp reference route, on the same batch.
+
+    Returns the routed session, its metrics and ``z_vs_ref``: max
+    |z - z_ref| / (1 + |z_ref|) over every worker's dual replica.
+    """
+    with _kernels("ref"):
+        ref, _ = _one_epoch(cfg, mesh, train, consensus, batch, b)
+    z_ref = jax.tree.leaves(jax.device_get(ref.state["z"]))
+    del ref
+    session, metrics = _one_epoch(cfg, mesh, train, consensus, batch, b)
+    z = jax.tree.leaves(jax.device_get(session.state["z"]))
+    diff = max(float(np.max(np.abs(got - want) / (1.0 + np.abs(want))))
+               for got, want in zip(z, z_ref))
+    return session, metrics, {"z_vs_ref": diff}
+
+
+def four_chip_phase(cfg, *, consensus: str = "gossip", seq_len: int = 128,
+                    batch_per_worker: int = 2, seed: int = 0) -> dict:
+    """One exact-consensus and one ring-gossip epoch (``consensus``) on the
+    same batch over a 4x1 (data, model) mesh, every worker at its full
+    minibatch.
 
     Metropolis mixing is doubly stochastic, so with equal b_i the
     node-averaged gossip dual equals the exact eq.-6 sum, and the
-    node-averaged primal (``AMBSession.params``) the exact primal.
+    node-averaged primal (``AMBSession.params``) the exact primal.  That
+    holds for any doubly stochastic mixing, so each worker's dual replica
+    is also compared with the same epoch on the jnp reference route.
     """
     mesh = make_mesh((4, 1), ("data", "model"))
     train = TrainSpec(arch=ARCH, data=4, model=1, seq_len=seq_len,
                       batch_per_worker=batch_per_worker, seed=seed)
-    clock = ClockSpec(kind="simulated")
-    exact = AMBSession(train, clock, ConsensusSpec(consensus="exact"),
-                       mesh=mesh, cfg=cfg)
-    gossip = AMBSession(train, clock,
-                        ConsensusSpec(consensus="gossip", graph="ring"),
-                        mesh=mesh, cfg=cfg)
+    exact = AMBSession(train, ClockSpec(kind="simulated"),
+                       ConsensusSpec(consensus="exact"), mesh=mesh, cfg=cfg)
     batch = exact.batch_source().batch(0)
     b = jnp.full((4,), batch_per_worker, jnp.int32)
     m_exact = exact.step(batch, b)
-    m_gossip = gossip.step(batch, b)
-
     w_e = jax.tree.leaves(exact.params)
+    # the exact session's FSDP-sharded embedding: a quarter per device
+    emb = exact.state["params"]["embed"]
+    emb_shards = {s.device: s.data.shape for s in emb.addressable_shards}
+    fsdp_ok = (len(emb_shards) == 4 and all(
+        int(np.prod(s)) * 4 == emb.size for s in emb_shards.values()))
+    del exact, emb
+    gossip, m_gossip, vs_ref = gossip_vs_ref(
+        cfg, mesh, train, ConsensusSpec(consensus=consensus, graph="ring"),
+        batch, b)
+
     w_g = jax.tree.leaves(gossip.params)
     primal = max(float(jnp.max(jnp.abs(g.astype(jnp.float32)
                                        - e.astype(jnp.float32))
@@ -229,15 +285,10 @@ def four_chip_phase(cfg, *, seq_len: int = 128, batch_per_worker: int = 2,
     replicas_ok = all(
         sorted(rows.values()) == [(i, i + 1) for i in range(4)]
         and len(rows) == 4 for rows in z_rows)
-    # the exact session's FSDP-sharded embedding: a quarter per device
-    emb = exact.state["params"]["embed"]
-    emb_shards = {s.device: s.data.shape for s in emb.addressable_shards}
-    fsdp_ok = (len(emb_shards) == 4 and all(
-        int(np.prod(s)) * 4 == emb.size for s in emb_shards.values()))
     in_use = [d.memory_stats()["bytes_in_use"] for d in devices] \
         if devices[0].memory_stats() else []
     return {"loss_exact": m_exact["loss"], "loss_gossip": m_gossip["loss"],
-            "primal_diff": primal, "replicas_ok": replicas_ok,
+            "primal_diff": primal, **vs_ref, "replicas_ok": replicas_ok,
             "fsdp_ok": fsdp_ok, "bytes_in_use": in_use}
 
 
@@ -279,6 +330,7 @@ def run_one_chip(args) -> None:
     _log(phase="train", **train, compile_s=timer.seconds)
     _check(len(losses) == STEPS and all(np.isfinite(losses)),
            f"training losses not all finite: {losses}")
+    _log(phase="batch", batch_s=batch_seconds(session))
 
     diffs = dual_update_check(session)
     _log(phase="dual_update", **diffs, kernel_tol=DUAL_UPDATE_TOL,
@@ -299,18 +351,20 @@ def run_one_chip(args) -> None:
 
 
 def run_four_chips(args) -> None:
-    cfg = dataclasses.replace(get_config(ARCH), num_layers=FOUR_CHIP_LAYERS,
-                              vocab_size=FOUR_CHIP_VOCAB)
+    cfg = dataclasses.replace(get_config(ARCH), num_layers=FOUR_CHIP_LAYERS)
     _log(phase="config", arch=ARCH, num_layers=cfg.num_layers,
+         published_layers=get_config(ARCH).num_layers,
          vocab=cfg.vocab_size, params=cfg.param_count(), mesh="4x1")
     timer = CompileTimer()
     out = four_chip_phase(cfg, seed=args.seed)
     _log(phase="four_chips", **out, primal_tol=PRIMAL_TOL,
-         compile_s=timer.seconds)
+         gossip_tol=GOSSIP_TOL, compile_s=timer.seconds)
     _check(np.isfinite(out["loss_exact"]) and np.isfinite(out["loss_gossip"]),
            "four-chip losses not finite")
     _check(out["primal_diff"] <= PRIMAL_TOL,
            "gossip node-averaged primal differs from exact consensus")
+    _check(out["z_vs_ref"] <= GOSSIP_TOL,
+           "gossip dual replicas differ from the jnp reference route")
     _check(out["replicas_ok"], "dual replicas are not one per device")
     _check(out["fsdp_ok"], "parameters are not sharded over the devices")
     in_use = out["bytes_in_use"]

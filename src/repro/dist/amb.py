@@ -42,7 +42,9 @@ from ..core import consensus as cns
 from ..core.dual_averaging import BetaSchedule
 from .consensus import (ConsensusStrategy, GossipConsensus, make_strategy,
                         torus_shape_for_mesh)
+from .params import tree_shardings
 from .redundancy import CodedAssignment, epoch_weights
+from .sharding import worker_axes
 
 Array = jax.Array
 
@@ -84,7 +86,8 @@ def strategy_from_config(amb: AMBConfig, mesh) -> ConsensusStrategy:
         tshape = torus_shape_for_mesh(mesh)
     return make_strategy(amb.consensus, n, rounds=amb.gossip_rounds,
                          graph=amb.graph, lazy=amb.lazy, torus_shape=tshape,
-                         active=amb.active, relayout=amb.relayout)
+                         active=amb.active, relayout=amb.relayout,
+                         mesh=mesh)
 
 
 def assignment_from_config(amb: AMBConfig, n: int
@@ -98,11 +101,6 @@ def assignment_from_config(amb: AMBConfig, n: int
 # ---------------------------------------------------------------------------
 # Workers and variable-minibatch masking
 # ---------------------------------------------------------------------------
-
-def worker_axes(mesh) -> tuple:
-    """Mesh axes that enumerate AMB workers (everything but "model")."""
-    return tuple(a for a in mesh.axis_names if a != "model")
-
 
 def num_workers(mesh) -> int:
     """Workers = product of the non-"model" axis extents (pod x data)."""
@@ -210,8 +208,9 @@ def make_train_step(cfg, opt, mesh, amb: AMBConfig = AMBConfig()):
     axes); ``b`` the (n_workers,) per-worker minibatch sizes for this
     epoch.  The weighted loss's gradient equals the paper's eq.-6 global
     gradient, and ``opt`` applies the update (dual averaging: z += g,
-    w = prox(z, beta)).  Under coded redundancy (``amb.redundancy > 1``)
-    the 0/1 eq.-3 weights become the ``1/copies`` decode weights of
+    w = prox(z, beta)) on the parameters' :func:`tree_shardings` layout.
+    Under coded redundancy (``amb.redundancy > 1``) the 0/1 eq.-3
+    weights become the ``1/copies`` decode weights of
     :mod:`repro.dist.redundancy` and ``global_batch`` counts *distinct*
     covered samples.
     """
@@ -234,7 +233,9 @@ def make_train_step(cfg, opt, mesh, amb: AMBConfig = AMBConfig()):
             return total, m
 
         (_, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_params, new_state = opt.apply(grads, opt_state, params)
+        new_params, new_state = opt.apply(
+            grads, opt_state, params,
+            shardings=tree_shardings(params, mesh))
         metrics = {"loss": m["loss"], "aux": m["aux"], "ntok": m["ntok"],
                    "global_batch": gbatch}
         return new_params, new_state, metrics
@@ -316,8 +317,9 @@ def _init_gossip_state(params, mesh, n, waxes):
     zshard = NamedSharding(mesh, P(waxes if n > 1 else None))
 
     def zeros(p):
-        return jax.device_put(jnp.zeros((n,) + p.shape, jnp.float32),
-                              zshard)
+        # made in place on every device: a (n, *param) f32 stack built on
+        # one device first would need n times that worker's share there
+        return jnp.zeros((n,) + p.shape, jnp.float32, device=zshard)
 
     return {"z": jax.tree.map(zeros, params),
             "w0": params,            # prox anchor w(1), original dtypes

@@ -49,11 +49,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import consensus as cns
 from ..kernels import ops as kops
-from .sharding import active_mesh
+from ..kernels.gossip_combine import ALIGN
+from .sharding import worker_axes
 
 Array = jax.Array
 
@@ -296,18 +297,12 @@ def _mask_rows(out: Array, orig: Array, active) -> Array:
     return jnp.where(mask, out, orig)
 
 
-def _row_spec(ndim: int) -> P:
-    """Layout of a worker-row stack ``(n, ...)`` on the ambient mesh: rows
-    over the worker axes (every axis but "model")."""
-    mesh = active_mesh()
-    waxes = () if mesh is None else tuple(
-        a for a in mesh.axis_names if a != "model")
-    return P(waxes or None, *([None] * (ndim - 1)))
-
-
-def _roll_taps(m: Array, taps) -> Array:
-    """Stack the neighbor views: (K, n, ...) from (n, ...)."""
-    return jnp.stack([taps.take(m, i) for i in range(taps.k)])
+def _row_sharding(mesh) -> Optional[NamedSharding]:
+    """Layout of a worker-row stack ``(n, ...)`` on ``mesh``: rows over
+    the worker axes.  The routed kernels run per device under it."""
+    if mesh is None:
+        return None
+    return NamedSharding(mesh, P(worker_axes(mesh) or None))
 
 
 # ---------------------------------------------------------------------------
@@ -364,8 +359,9 @@ class _TapGossip(ConsensusStrategy):
     def __init__(self, n: int, rounds: int, graph: str = "ring",
                  lazy: float = 0.5, torus_shape: Optional[tuple] = None,
                  active: Optional[Sequence[bool]] = None,
-                 relayout: bool = True):
+                 relayout: bool = True, mesh=None):
         self.n = int(n)
+        self.rows = _row_sharding(mesh)
         self.rounds = int(rounds)
         self.graph = graph
         self.lazy = float(lazy)
@@ -437,13 +433,18 @@ class GossipConsensus(_TapGossip):
             return cns.gossip(m, jnp.asarray(self.p, jnp.float32),
                               self.rounds)
         w = jnp.asarray(self.taps.weights)
-        spec = _row_spec(m.ndim)
+        # whole (8, 128) f32 tiles per row, once: the combine kernel then
+        # reads every round's neighbour views in place
+        d = m.shape[1]
+        pad = (-d) % ALIGN
 
         def one_round(_, cur):
-            return kops.gossip_combine(_roll_taps(cur, self.taps), w,
-                                       spec=spec)
+            return kops.gossip_combine(
+                [self.taps.take(cur, i) for i in range(self.taps.k)], w,
+                sharding=self.rows)
 
-        out = jax.lax.fori_loop(0, self.rounds, one_round, m)
+        out = jax.lax.fori_loop(0, self.rounds, one_round,
+                                jnp.pad(m, ((0, 0), (0, pad))))[:, :d]
         # survivor relayout: inactive workers keep their rows (identity);
         # no active row ever reads an inactive one, so one final select
         # equals the dense masked operator's per-round identity rows
@@ -471,9 +472,9 @@ class QuantizedGossipConsensus(_TapGossip):
                  graph: str = "ring", lazy: float = 0.5,
                  torus_shape: Optional[tuple] = None,
                  active: Optional[Sequence[bool]] = None,
-                 relayout: bool = True):
+                 relayout: bool = True, mesh=None):
         super().__init__(n, rounds, graph, lazy, torus_shape, active,
-                         relayout)
+                         relayout, mesh)
         if bits not in (4, 8):
             raise ValueError("bits must be 4 or 8 (uint8 wire container)")
         self.bits = int(bits)
@@ -518,7 +519,6 @@ class QuantizedGossipConsensus(_TapGossip):
         d = m.shape[1]
         w = jnp.asarray(taps.weights)
         km1 = taps.k - 1
-        spec = _row_spec(m.ndim)
 
         def one_round(k_round, carry):
             cur, h, hnbr = carry
@@ -535,7 +535,7 @@ class QuantizedGossipConsensus(_TapGossip):
                 rnd = jax.random.uniform(jax.random.fold_in(key, k_round),
                                          cur.shape)
             lvl, h_new = kops.stochastic_quantize(cur, h, rnd, lo, scale,
-                                                  levels, spec=spec)
+                                                  levels, sharding=self.rows)
             # -- the wire: rolled (nibble-packed) level planes + scalars.
             # The barriers pin the collective-permute to the uint8 plane:
             # without them XLA hoists the u8->f32 dequant (and the 4-bit
@@ -552,7 +552,7 @@ class QuantizedGossipConsensus(_TapGossip):
                               for j in range(1, taps.k)])
             # -- receive half: fused dequantize + replica update + combine
             out, hnbr_new = kops.quantized_combine(
-                cur, hnbr, lvl_r, lo_r, sc_r, w, spec=spec)
+                cur, hnbr, lvl_r, lo_r, sc_r, w, sharding=self.rows)
             return out, h_new, hnbr_new
 
         h0 = jnp.zeros_like(m)
@@ -584,7 +584,7 @@ def torus_shape_for_mesh(mesh) -> Optional[tuple]:
     along exactly one physical mesh axis.  Single-worker-axis meshes fall
     back to the most-square factorization.
     """
-    waxes = [a for a in mesh.axis_names if a != "model"]
+    waxes = worker_axes(mesh)
     if len(waxes) == 2:
         return int(mesh.shape[waxes[0]]), int(mesh.shape[waxes[1]])
     return None
@@ -597,7 +597,7 @@ def make_strategy(name: str, n: int, *, rounds: int = 5,
                   graph: str = "ring", lazy: float = 0.5,
                   torus_shape: Optional[tuple] = None,
                   active: Optional[Sequence[bool]] = None,
-                  relayout: bool = True) -> ConsensusStrategy:
+                  relayout: bool = True, mesh=None) -> ConsensusStrategy:
     """Build a strategy from the AMBConfig vocabulary.
 
     ``name`` in {"exact", "gossip", "gossip_q8", "gossip_q4"}.  Quantized
@@ -609,17 +609,18 @@ def make_strategy(name: str, n: int, *, rounds: int = 5,
     :func:`masked_metropolis` operator), non-circulant graphs take the
     dense induced-subgraph operator.  Exact consensus needs no rebuild —
     a departed worker's zero-weighted message (b_i = 0) already drops
-    out of the eq.-6 average.
+    out of the eq.-6 average.  ``mesh``: the mesh whose worker axes hold
+    the message rows; the gossip kernels run per device on it.
     """
     if name == "exact":
         return ExactConsensus(n)
     if name == "gossip":
         return GossipConsensus(n, rounds, graph, lazy, torus_shape, active,
-                               relayout)
+                               relayout, mesh)
     if name in ("gossip_q8", "gossip_q4"):
         bits = int(name[-1])
         return QuantizedGossipConsensus(n, rounds * 32 // bits, bits,
                                         graph, lazy, torus_shape, active,
-                                        relayout)
+                                        relayout, mesh)
     raise ValueError(f"unknown consensus strategy {name!r}; "
                      f"choose from {CONSENSUS_CHOICES}")

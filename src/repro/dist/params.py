@@ -26,8 +26,6 @@ from typing import Optional
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .sharding import active_mesh
-
 # Leaves whose *first* of the two trailing dims is the wide (TP) side.
 _OUT_PROJ = frozenset({"wo", "w_down", "w_out", "decay_b"})
 # MoE in-projections: (E, d, ff) — d_model is the middle dim.
@@ -83,13 +81,6 @@ def _path_name(path) -> str:
         out.append(str(getattr(k, "key", getattr(k, "idx",
                                                  getattr(k, "name", k)))))
     return "/".join(out)
-
-
-def ambient_spec(path, shape) -> P:
-    """:func:`param_spec` of the leaf at ``path`` on the ambient mesh
-    (:func:`repro.dist.sharding.use_sharding`); ``P()`` without one."""
-    mesh = active_mesh()
-    return P() if mesh is None else param_spec(_path_name(path), shape, mesh)
 
 
 def tree_shardings(tree, mesh, fsdp_axis: Optional[str] = "data"):

@@ -52,6 +52,11 @@ def use_sharding(mesh):
         _STATE.mesh = prev
 
 
+def worker_axes(mesh) -> tuple:
+    """Mesh axes that enumerate AMB workers (everything but "model")."""
+    return tuple(a for a in mesh.axis_names if a != "model")
+
+
 def _resolve(mesh, logical: Optional[str], dim: int):
     """Mesh axes for one logical axis on a dim of extent ``dim`` (or None)."""
     if logical is None:

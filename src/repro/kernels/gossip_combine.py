@@ -5,8 +5,8 @@ line 16, plus the CHOCO-style delta compression of
 :func:`repro.core.extensions.gossip_quantized`):
 
   * :func:`gossip_combine_pallas` — fp32 K-way weighted combine
-    ``out = sum_k w_k * msg_k``: the K neighbor messages arrive stacked
-    (K, N) after the collective_permute exchange and the weighted
+    ``out = sum_k w_k * msg_k``: the K neighbor messages arrive as K
+    arrays after the collective_permute exchange and the weighted
     accumulation is fused in a single VMEM pass instead of K separate
     scale-and-adds over an HBM-resident model-sized buffer.
 
@@ -40,39 +40,51 @@ Array = jax.Array
 LANE = 128
 
 
-def _kernel(msgs_ref, w_ref, o_ref, *, k: int):
-    acc = jnp.zeros(o_ref.shape, jnp.float32)
-    for j in range(k):
-        acc = acc + w_ref[0, j] * msgs_ref[j:j + 1, :].astype(jnp.float32)
+# f32 elements of one (8, 128) tile: a message whose size is a multiple of
+# this views as lane-dense (rows, 128) tiles without a copy
+ALIGN = 8 * LANE
+
+
+def _kernel(w_ref, *refs, k: int):
+    msg_refs, o_ref = refs[:k], refs[k]
+    acc = w_ref[0, 0] * msg_refs[0][...]
+    for j in range(1, k):
+        acc = acc + w_ref[0, j] * msg_refs[j][...]
     o_ref[...] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def gossip_combine_pallas(msgs: Array, weights: Array, *,
-                          block: int = 512 * LANE,
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def gossip_combine_pallas(msgs, weights: Array, *, block_rows: int = 512,
                           interpret: bool = False) -> Array:
-    """msgs: (K, N); weights: (K,). Returns (N,) fp32.
+    """msgs: K arrays of N elements each; weights: (K,). Returns (N,) fp32.
 
-    Tiles the message axis in place: the last block runs past N and
-    Pallas masks its writes, so no padded copy of the model-sized
-    messages is made (padding an N that is not a multiple of 128 and
-    reshaping it also makes the TPU compile time grow with N).
+    The K messages come as separate arrays (a (K, N) array also iterates
+    as K rows), so the caller never stacks K model-sized copies.  Each is
+    viewed as (N / 128, 128) lane-dense rows, in place when N is a
+    multiple of :data:`ALIGN`; any other N is padded (a copy) first.
     """
-    k, n = msgs.shape
-    block = min(block, n)       # a block wider than N must equal N
-    w2 = weights.astype(jnp.float32).reshape(1, k)
+    msgs = [m.reshape(-1).astype(jnp.float32) for m in msgs]
+    k, n = len(msgs), msgs[0].size
+    pad = (-n) % ALIGN
+    if pad:
+        msgs = [jnp.pad(m, (0, pad)) for m in msgs]
+    rows = (n + pad) // LANE
+    block_rows = min(block_rows, rows)
+    tile = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, k=k),
-        grid=(pl.cdiv(n, block),),
-        in_specs=[
-            pl.BlockSpec((k, block), lambda i: (0, i)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        grid=(pl.cdiv(rows, block_rows),),
+        in_specs=[pl.BlockSpec((1, k), lambda i: (0, 0))] + [tile] * k,
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+        # block i of the output reads only block i of each message, so it
+        # may overwrite the first (self) message in place
+        input_output_aliases={1: 0},
         interpret=interpret,
-    )(msgs, w2)
-    return out.reshape(n)
+    )(weights.astype(jnp.float32).reshape(1, k),
+      *[m.reshape(rows, LANE) for m in msgs])
+    out = out.reshape(-1)
+    return out[:n] if pad else out
 
 
 # ---------------------------------------------------------------------------

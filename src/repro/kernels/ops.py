@@ -7,11 +7,10 @@ via ``REPRO_KERNELS`` / ``TrainSpec.kernels`` and per call via ``force``
 (the test suite's oracle sweeps).  The routing decision is made at trace
 time and logged once by the router.
 
-XLA cannot partition a Mosaic (Pallas TPU) kernel.  Under an ambient mesh
-of more than one device (:func:`repro.dist.sharding.use_sharding`) each
-kernel therefore runs inside ``shard_map`` on the local blocks; ``spec``
-names the layout of the caller's array (one parameter leaf, or one
-worker-row stack ``(n, ...)``) on that mesh.
+XLA cannot partition a Mosaic (Pallas TPU) kernel.  The elementwise
+kernels therefore take the caller's layout as ``sharding`` (a
+``NamedSharding`` of the array the caller passes): over more than one
+device the kernel runs inside ``shard_map`` on each device's block.
 """
 from __future__ import annotations
 
@@ -19,9 +18,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..dist.sharding import active_mesh
 from . import ref, router
 from .dual_update import dual_update_pallas
 from .flash_attention import flash_attention_pallas
@@ -32,13 +30,16 @@ from .rwkv6_scan import rwkv6_scan_pallas
 Array = jax.Array
 
 
-def _per_device(fn, in_specs, out_specs):
-    """``fn`` on each device's blocks under a multi-device ambient mesh."""
-    mesh = active_mesh()
-    if mesh is None or mesh.size == 1:
+def _per_device(fn, sharding: Optional[NamedSharding], in_specs, out_specs):
+    """``fn`` on each device's blocks when ``sharding`` spans devices."""
+    if sharding is None or sharding.mesh.size == 1:
         return fn
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=sharding.mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+
+def _spec(sharding: Optional[NamedSharding]) -> P:
+    return P() if sharding is None else sharding.spec
 
 
 def _stacked(spec: P) -> P:
@@ -48,20 +49,22 @@ def _stacked(spec: P) -> P:
 
 def dual_update(z: Array, w0: Array, beta: Array,
                 radius: Optional[float] = None,
-                force: Optional[str] = None, spec: P = P()) -> Array:
+                force: Optional[str] = None,
+                sharding: Optional[NamedSharding] = None) -> Array:
     """w = w0 - z/(2 beta), optionally projected onto ||w - w0|| <= radius.
 
-    ``spec``: the layout of ``z`` and ``w0`` on the ambient mesh.
+    ``sharding``: the layout of ``z`` and ``w0``.
     """
     impl = router.resolve(force)
     if impl == "ref":
         w = ref.dual_update_ref(z, w0, beta)
     else:
         interpret = impl == "pallas_interpret"
+        spec = _spec(sharding)
         w = _per_device(
             lambda z, w0, beta: dual_update_pallas(z, w0, beta,
                                                    interpret=interpret),
-            (spec, spec, P()), spec)(z, w0, beta)
+            sharding, (spec, spec, P()), spec)(z, w0, beta)
     if radius is not None:
         delta = w - w0.astype(jnp.float32)
         nrm = jnp.linalg.norm(delta.reshape(-1))
@@ -70,57 +73,64 @@ def dual_update(z: Array, w0: Array, beta: Array,
     return w
 
 
-def gossip_combine(msgs: Array, weights: Array,
-                   force: Optional[str] = None, spec: P = P()) -> Array:
-    """K-way weighted combine of stacked neighbor messages: (K, ...) -> (...).
+def gossip_combine(msgs, weights: Array, force: Optional[str] = None,
+                   sharding: Optional[NamedSharding] = None) -> Array:
+    """K-way weighted combine of neighbor messages: K arrays of one shape
+    (or a (K, ...) array) -> one array of that shape, fp32.
 
-    ``spec``: the layout of one message ``msgs[k]`` on the ambient mesh.
+    ``sharding``: the layout of each message.
     """
     impl = router.resolve(force)
     if impl == "ref":
         return ref.gossip_combine_ref(msgs, weights)
     interpret = impl == "pallas_interpret"
+    msgs = list(msgs)
+    spec = _spec(sharding)
 
-    def local(m, w):
-        out = gossip_combine_pallas(m.reshape(m.shape[0], -1), w,
-                                    interpret=interpret)
-        return out.reshape(m.shape[1:])
+    def local(ms, w):
+        out = gossip_combine_pallas(ms, w, interpret=interpret)
+        return out.reshape(ms[0].shape)
 
-    return _per_device(local, (_stacked(spec), P()), spec)(msgs, weights)
+    return _per_device(local, sharding, ([spec] * len(msgs), P()),
+                       spec)(msgs, weights)
 
 
 def stochastic_quantize(m: Array, h: Array, rnd: Array, lo: Array,
                         scale: Array, levels: float = 255.0,
-                        force: Optional[str] = None, spec: P = P()):
+                        force: Optional[str] = None,
+                        sharding: Optional[NamedSharding] = None):
     """Send half of a quantized gossip round: (levels u8, updated replica).
 
-    ``spec``: the layout of the ``(n, ...)`` worker-row arrays.
+    ``sharding``: the layout of the ``(n, ...)`` worker-row arrays.
     """
     impl = router.resolve(force)
     if impl == "ref":
         return ref.stochastic_quantize_ref(m, h, rnd, lo, scale, levels)
     interpret = impl == "pallas_interpret"
+    spec = _spec(sharding)
     return _per_device(
         lambda *a: stochastic_quantize_pallas(*a, levels=levels,
                                               interpret=interpret),
-        (spec,) * 5, (spec, spec))(m, h, rnd, lo, scale)
+        sharding, (spec,) * 5, (spec, spec))(m, h, rnd, lo, scale)
 
 
 def quantized_combine(m: Array, hnbr: Array, lvl: Array, lo: Array,
                       scale: Array, weights: Array,
-                      force: Optional[str] = None, spec: P = P()):
+                      force: Optional[str] = None,
+                      sharding: Optional[NamedSharding] = None):
     """Receive half: fused dequantize + replica update + K-way combine.
 
-    ``spec``: the layout of the ``(n, ...)`` worker-row arrays.
+    ``sharding``: the layout of the ``(n, ...)`` worker-row arrays.
     """
     impl = router.resolve(force)
     if impl == "ref":
         return ref.quantized_combine_ref(m, hnbr, lvl, lo, scale, weights)
     interpret = impl == "pallas_interpret"
+    spec = _spec(sharding)
     taps = _stacked(spec)
     return _per_device(
         lambda *a: quantized_combine_pallas(*a, interpret=interpret),
-        (spec, taps, taps, taps, taps, P()), (spec, taps))(
+        sharding, (spec, taps, taps, taps, taps, P()), (spec, taps))(
             m, hnbr, lvl, lo, scale, weights)
 
 

@@ -18,14 +18,20 @@ def dual_update_ref(z: Array, w0: Array, beta: Array) -> Array:
             - z.astype(jnp.float32) / (2.0 * beta.astype(jnp.float32)))
 
 
-def gossip_combine_ref(msgs: Array, weights: Array) -> Array:
+def gossip_combine_ref(msgs, weights: Array) -> Array:
     """Weighted neighbor combine: out = sum_k weights[k] * msgs[k].
 
-    msgs: (K, ...); weights: (K,).  This is one row of m <- P m restricted
-    to the K in-neighborhood messages (self included).
+    msgs: K arrays of one shape (or a (K, ...) array); weights: (K,).
+    This is one row of m <- P m restricted to the K in-neighborhood
+    messages (self included).  Elementwise f32 multiply-adds in tap order,
+    as the kernel does them (a contraction over K would run at the
+    backend's matmul precision).
     """
-    return jnp.einsum("k,k...->...", weights.astype(jnp.float32),
-                      msgs.astype(jnp.float32))
+    w = weights.astype(jnp.float32)
+    out = w[0] * msgs[0].astype(jnp.float32)
+    for j in range(1, len(msgs)):
+        out = out + w[j] * msgs[j].astype(jnp.float32)
+    return out
 
 
 def stochastic_quantize_ref(m: Array, h: Array, rnd: Array, lo: Array,

@@ -16,9 +16,13 @@ import jax
 from jax.sharding import AxisType
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagation)."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagation).
+
+    ``devices``: the devices to lay out (default: all of them), e.g. those
+    of a described topology for a compile with no chip attached.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
                          axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -28,8 +28,11 @@ class Optimizer:
     def init(self, params: PyTree) -> PyTree:
         raise NotImplementedError
 
-    def apply(self, grads: PyTree, state: PyTree,
-              params: PyTree) -> tuple[PyTree, PyTree]:
+    def apply(self, grads: PyTree, state: PyTree, params: PyTree,
+              shardings: Optional[PyTree] = None) -> tuple[PyTree, PyTree]:
+        """One update.  ``shardings``: the caller's ``NamedSharding`` per
+        parameter leaf, for an update that runs a kernel which must be
+        told the layout (None: unsharded)."""
         raise NotImplementedError
 
 
@@ -45,20 +48,19 @@ class DualAveragingOpt(Optimizer):
             "t": jnp.zeros((), jnp.int32),
         }
 
-    def apply(self, grads, state, params):
-        from ..dist.params import ambient_spec
+    def apply(self, grads, state, params, shardings=None):
         from ..kernels import ops as kops
         t_new = state["t"] + 1
         beta = self.beta(t_new.astype(jnp.float32) + 1.0)
         z_new = jax.tree.map(
             lambda z, g: z + g.astype(jnp.float32), state["z"], grads)
 
-        def prox(path, z, w0, p):
-            w = kops.dual_update(z, w0, beta, self.radius,
-                                 spec=ambient_spec(path, p.shape))
+        def prox(z, w0, p, sharding=None):
+            w = kops.dual_update(z, w0, beta, self.radius, sharding=sharding)
             return w.astype(p.dtype)
-        new_params = jax.tree_util.tree_map_with_path(
-            prox, z_new, state["w0"], params)
+        trees = (z_new, state["w0"], params)
+        new_params = jax.tree.map(
+            prox, *trees, *(() if shardings is None else (shardings,)))
         return new_params, {"z": z_new, "w0": state["w0"], "t": t_new}
 
 
@@ -76,7 +78,7 @@ class AdamW(Optimizer):
                 "v": jax.tree.map(zeros, params),
                 "t": jnp.zeros((), jnp.int32)}
 
-    def apply(self, grads, state, params):
+    def apply(self, grads, state, params, shardings=None):
         t = state["t"] + 1
         tf = t.astype(jnp.float32)
         c1 = 1.0 - self.b1 ** tf
@@ -112,7 +114,7 @@ class Sgd(Optimizer):
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)}
         return {}
 
-    def apply(self, grads, state, params):
+    def apply(self, grads, state, params, shardings=None):
         if self.momentum:
             v_new = jax.tree.map(
                 lambda v, g: self.momentum * v + g.astype(jnp.float32),

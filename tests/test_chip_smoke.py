@@ -58,19 +58,25 @@ def test_main_refuses_without_tpu(capsys):
     assert '"ok"' not in capsys.readouterr().out
 
 
-def test_four_chip_phase_on_host_devices():
+@pytest.mark.parametrize("consensus", ["gossip", "gossip_q8"])
+def test_four_chip_phase_on_host_devices(consensus):
     """The --four-chips phase on 4 forced host devices, every kernel in
-    interpret mode under shard_map: gossip's node-averaged primal equals
-    exact consensus, one dual replica per device, FSDP-sharded params."""
-    out = run_sub("""
+    interpret mode under shard_map: each worker's dual replica equals the
+    same epoch on the jnp reference route, one dual replica per device,
+    FSDP-sharded params; for fp32 gossip, the node-averaged primal equals
+    exact consensus."""
+    out = run_sub(f"""
         import chip_smoke
         from repro.configs import smoke_config
         from repro.kernels import router
         router.set_mode("pallas_interpret")
         out = chip_smoke.four_chip_phase(smoke_config(chip_smoke.ARCH),
+                                         consensus="{consensus}",
                                          seq_len=32)
-        assert out["primal_diff"] <= chip_smoke.PRIMAL_TOL, out
+        assert out["z_vs_ref"] <= chip_smoke.GOSSIP_TOL, out
+        if "{consensus}" == "gossip":
+            assert out["primal_diff"] <= chip_smoke.PRIMAL_TOL, out
         assert out["replicas_ok"] and out["fsdp_ok"], out
-        print("FOUR_OK")
+        print("FOUR_OK", out)
     """, devices=4)
     assert "FOUR_OK" in out

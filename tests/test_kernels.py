@@ -47,7 +47,7 @@ def test_gossip_combine_sweep(k, n):
     key = jax.random.PRNGKey(1)
     msgs = jax.random.normal(key, (k, n))
     w = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 1), (k,)))
-    got = gossip_combine_pallas(msgs, w, interpret=True, block=256)
+    got = gossip_combine_pallas(msgs, w, interpret=True, block_rows=8)
     want = ref.gossip_combine_ref(msgs, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

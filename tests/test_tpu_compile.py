@@ -23,9 +23,13 @@ from repro.kernels import ops
 EMBED = (151936, 1536)          # embed / unembed
 NORM = (1536,)                  # final_norm, ln1/ln2 rows
 KV_BIAS = (12, 256)             # bk / bv stacked over 12 layers
-# one worker's gossip payload on the four-chip mesh: every parameter of
-# the one-layer, 8192-vocabulary cut plus the eq.-6 weight column
-PAYLOAD = 71961600 + 1
+# one worker's gossip row on the four-chip mesh: every parameter of the
+# one-layer cut plus the eq.-6 weight column, padded to whole f32 tiles
+PAYLOAD = 513546752 + 1 + 511
+# the quantized kernels pad and copy their operands per call: at PAYLOAD
+# that program needs 18.2 GiB, so they are compiled at the one-layer cut
+# with an 8,192 vocabulary (still model-sized, not a multiple of 128)
+Q_PAYLOAD = 71961600 + 1
 TAPS = 3                        # ring: self + two neighbours
 
 
@@ -68,12 +72,12 @@ def test_dual_update_compiles(one_chip, shape):
 
 @pytest.mark.parametrize("n", [PAYLOAD, 999], ids=["payload", "short"])
 def test_gossip_combine_compiles(one_chip, n):
-    _compile(lambda m, w: ops.gossip_combine(m, w, force="pallas"),
-             one_chip, ((TAPS, n), jnp.float32), ((TAPS,), jnp.float32))
+    _compile(lambda w, *m: ops.gossip_combine(m, w, force="pallas"),
+             one_chip, ((TAPS,), jnp.float32), *[((n,), jnp.float32)] * TAPS)
 
 
 def test_stochastic_quantize_compiles(one_chip):
-    row = ((1, PAYLOAD), jnp.float32)
+    row = ((1, Q_PAYLOAD), jnp.float32)
     col = ((1, 1), jnp.float32)
     _compile(lambda m, h, r, lo, sc: ops.stochastic_quantize(
         m, h, r, lo, sc, 15.0, force="pallas"),
@@ -84,8 +88,8 @@ def test_quantized_combine_compiles(one_chip):
     taps = TAPS - 1
     _compile(lambda m, h, lvl, lo, sc, w: ops.quantized_combine(
         m, h, lvl, lo, sc, w, force="pallas"),
-        one_chip, ((1, PAYLOAD), jnp.float32),
-        ((taps, 1, PAYLOAD), jnp.float32),
-        ((taps, 1, PAYLOAD), jnp.uint8),
+        one_chip, ((1, Q_PAYLOAD), jnp.float32),
+        ((taps, 1, Q_PAYLOAD), jnp.float32),
+        ((taps, 1, Q_PAYLOAD), jnp.uint8),
         ((taps, 1, 1), jnp.float32), ((taps, 1, 1), jnp.float32),
         ((TAPS,), jnp.float32))
