@@ -51,6 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..ckpt import load_checkpoint, save_checkpoint
 from ..configs import get_config, smoke_config
@@ -71,6 +72,14 @@ from .protocol import build_protocol
 from .specs import ClockSpec, ConsensusSpec, ControllerSpec, TrainSpec
 
 Array = jax.Array
+
+# profiler span names (stable: the benchmark's per-layer metrics read them)
+SPAN_EPOCH = "amb.epoch"
+SPAN_CLOCK = "amb.epoch.clock"
+SPAN_DISPATCH = "amb.epoch.dispatch"
+SPAN_WAIT = "amb.epoch.wait"
+SPAN_RECORD = "amb.epoch.record"
+SPAN_ON_STEP = "amb.on_step"
 
 
 def _unalias(state):
@@ -330,61 +339,78 @@ class AMBSession:
         ``b`` overrides the clock-derived per-worker minibatch sizes
         (sized ``(n_workers,)``); by default the clock draws this epoch's
         per-gradient times and the deadline T decides b_i(t).
+
+        The epoch and its phases are profiler spans (``amb.epoch`` and
+        its ``.clock``, ``.dispatch``, ``.wait`` and ``.record``
+        children, each with the stat ``epoch``); ``.wait`` is the one
+        blocking read of the step's results.
         """
-        with use_sharding(self.mesh):
-            skey = jax.random.fold_in(self._key, 10_000 + self.steps_done)
-            times, budget = self.clock.epoch(skey)
-            if self._slow is not None:
-                # fault-injected degradation: scale each worker's
-                # per-gradient times; the deadline cut below turns the
-                # slowdown into a smaller b_i(t) automatically
-                times = times * jnp.asarray(self._slow,
-                                            times.dtype)[:, None]
-            if b is None:
-                b = self.epoch_sizes(times, budget)
-            # simulated wall clock: pipelined epochs hide T_c under the
-            # next epoch's compute; async epochs give each consensus D
-            # compute windows, so only T_c/D must fit per epoch; FMB
-            # waits for the slowest worker
-            if self.train.mode == "amb":
-                spec = self.consensus_spec
-                if spec.async_epochs:
-                    self.sim_wall += max(
-                        float(budget),
-                        self.clock_spec.comm_time / spec.staleness)
-                elif spec.pipeline:
-                    self.sim_wall += max(float(budget),
-                                         self.clock_spec.comm_time)
-                else:
-                    self.sim_wall += (float(budget)
-                                      + self.clock_spec.comm_time)
-            else:
-                self.sim_wall += float(jnp.max(fmb_finish_times(
-                    times, self.train.batch_per_worker))) \
-                    + self.clock_spec.comm_time
-            batch = put_batch(batch, self.mesh, self._batch_axes)
-            t0 = time.time()
-            self.state, m = self._step_fn(self.state, batch, b)
-            loss = float(m["loss"])
-            step_s = time.time() - t0
-            self.clock.update(step_s, float(m["global_batch"]))
-            self.steps_done += 1
-            out = {"loss": loss,
-                   "global_batch": float(m["global_batch"]),
-                   "budget_s": float(budget),
-                   "step_s": step_s,
-                   "sim_wall_s": self.sim_wall,
-                   "staleness": self.consensus_spec.staleness,
-                   "b": np.asarray(b)}
-            if self.controller is not None:
-                action = self._control(m, out, b, times)
-                if action is not None:
-                    out["action"] = action.to_dict()
-            if self.metrics is not None:
-                self.metrics.log(self.steps_done,
-                                 **{k: v for k, v in out.items()
-                                    if k != "b"})
+        epoch = self.steps_done
+        with use_sharding(self.mesh), TraceAnnotation(SPAN_EPOCH,
+                                                      epoch=epoch):
+            with TraceAnnotation(SPAN_CLOCK, epoch=epoch):
+                times, budget = self._draw()
+                if b is None:
+                    b = self.epoch_sizes(times, budget)
+            with TraceAnnotation(SPAN_DISPATCH, epoch=epoch):
+                batch = put_batch(batch, self.mesh, self._batch_axes)
+                t0 = time.time()
+                self.state, m = self._step_fn(self.state, batch, b)
+            with TraceAnnotation(SPAN_WAIT, epoch=epoch):
+                loss, gbatch, b = jax.device_get(
+                    (m["loss"], m["global_batch"], b))
+                step_s = time.time() - t0
+                b = np.asarray(b)
+            with TraceAnnotation(SPAN_RECORD, epoch=epoch,
+                                 credited=int(b.sum()),
+                                 computed=self.global_batch):
+                self.clock.update(step_s, float(gbatch))
+                self.steps_done += 1
+                out = {"loss": float(loss),
+                       "global_batch": float(gbatch),
+                       "budget_s": float(budget),
+                       "step_s": step_s,
+                       "sim_wall_s": self.sim_wall,
+                       "staleness": self.consensus_spec.staleness,
+                       "b": b}
+                if self.controller is not None:
+                    action = self._control(m, out, b, times)
+                    if action is not None:
+                        out["action"] = action.to_dict()
+                if self.metrics is not None:
+                    self.metrics.log(self.steps_done,
+                                     **{k: v for k, v in out.items()
+                                        if k != "b"})
             return out
+
+    def _draw(self) -> tuple:
+        """This epoch's per-gradient times and budget T from the clock;
+        advances the simulated wall clock."""
+        skey = jax.random.fold_in(self._key, 10_000 + self.steps_done)
+        times, budget = self.clock.epoch(skey)
+        if self._slow is not None:
+            # fault-injected degradation: scale each worker's
+            # per-gradient times; the deadline cut turns the slowdown
+            # into a smaller b_i(t) automatically
+            times = times * jnp.asarray(self._slow, times.dtype)[:, None]
+        # simulated wall clock: pipelined epochs hide T_c under the
+        # next epoch's compute; async epochs give each consensus D
+        # compute windows, so only T_c/D must fit per epoch; FMB
+        # waits for the slowest worker
+        if self.train.mode == "amb":
+            spec = self.consensus_spec
+            if spec.async_epochs:
+                self.sim_wall += max(
+                    float(budget), self.clock_spec.comm_time / spec.staleness)
+            elif spec.pipeline:
+                self.sim_wall += max(float(budget), self.clock_spec.comm_time)
+            else:
+                self.sim_wall += float(budget) + self.clock_spec.comm_time
+        else:
+            self.sim_wall += float(jnp.max(fmb_finish_times(
+                times, self.train.batch_per_worker))) \
+                + self.clock_spec.comm_time
+        return times, budget
 
     def batch_source(self) -> StreamSource:
         """The session's default input: per-worker shards of the arch's
@@ -445,23 +471,32 @@ class AMBSession:
                     injector.apply(self, epoch)
                 out = self.step(source.batch(epoch))
                 if on_step is not None:
-                    on_step(self.steps_done - 1, out)
+                    self._on_step(on_step, out)
             return out
         pf = Prefetcher(source, self.mesh, self._batch_axes,
                         depth=prefetch, start_epoch=self.steps_done,
                         steps=steps)
         try:
-            for batch in pf:
+            # exactly ``steps`` takes, so no amb.data.wait span waits on
+            # the end-of-stream marker
+            for _ in range(steps):
+                batch = next(pf)
                 # the prefetcher yields epochs in order from steps_done,
                 # so the incoming batch's epoch IS the current counter
                 if injector is not None:
                     injector.apply(self, self.steps_done)
                 out = self.step(batch)
                 if on_step is not None:
-                    on_step(self.steps_done - 1, out)
+                    self._on_step(on_step, out)
         finally:
             pf.close()
         return out
+
+    def _on_step(self, on_step, out: dict) -> None:
+        """The caller's per-epoch callback, under its own span."""
+        epoch = self.steps_done - 1
+        with TraceAnnotation(SPAN_ON_STEP, epoch=epoch):
+            on_step(epoch, out)
 
     def _control(self, m: dict, out: dict, b: Array, times: Array):
         """Feed the epoch to the controller; apply any action in-place."""

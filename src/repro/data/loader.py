@@ -50,8 +50,14 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 Array = jax.Array
+
+# profiler span names (stable: the benchmark's per-layer metrics read them)
+SPAN_WAIT = "amb.data.wait"
+SPAN_BUILD = "amb.data.build"
+SPAN_PUT = "amb.data.put"
 
 
 # ---------------------------------------------------------------------------
@@ -257,6 +263,10 @@ class Prefetcher:
     stops the thread mid-``put`` via the queue's timeout poll).  A
     source exception is re-raised in the consumer on the corresponding
     ``next`` call.
+
+    Profiler spans, each with the stat ``epoch``: ``amb.data.build``
+    and ``amb.data.put`` on the thread, ``amb.data.wait`` for the
+    consumer blocked on the queue.
     """
 
     _STOP = object()
@@ -275,6 +285,7 @@ class Prefetcher:
         self._stop = threading.Event()
         self._epochs = itertools.count(start_epoch) if steps is None \
             else iter(range(start_epoch, start_epoch + steps))
+        self._next_epoch = start_epoch    # of the consumer's next batch
         self._thread = threading.Thread(target=self._fill, daemon=True,
                                         name="amb-prefetcher")
         self._thread.start()
@@ -301,7 +312,11 @@ class Prefetcher:
             for epoch in self._epochs:
                 if self._stop.is_set():
                     return
-                self._enqueue((epoch, self._put(self.source.batch(epoch))))
+                with TraceAnnotation(SPAN_BUILD, epoch=epoch):
+                    batch = self.source.batch(epoch)
+                with TraceAnnotation(SPAN_PUT, epoch=epoch):
+                    batch = self._put(batch)
+                self._enqueue((epoch, batch))
         except BaseException as e:                  # re-raised in next()
             self._enqueue((None, e))
             return
@@ -313,11 +328,13 @@ class Prefetcher:
     def __next__(self):
         if self._stop.is_set():
             raise StopIteration
-        epoch, item = self._q.get()
+        with TraceAnnotation(SPAN_WAIT, epoch=self._next_epoch):
+            epoch, item = self._q.get()
         if epoch is None:
             if item is self._STOP:
                 raise StopIteration
             raise item
+        self._next_epoch = epoch + 1
         return item
 
     def close(self) -> None:

@@ -232,10 +232,13 @@ def make_train_step(cfg, opt, mesh, amb: AMBConfig = AMBConfig()):
             total, m = lm_loss(p, cfg, batch, sw)
             return total, m
 
-        (_, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_params, new_state = opt.apply(
-            grads, opt_state, params,
-            shardings=tree_shardings(params, mesh))
+        # stable step-phase names for the profiler's op metadata
+        with jax.named_scope("amb.fwd_bwd"):
+            (_, m), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        with jax.named_scope("amb.dual_update"):
+            new_params, new_state = opt.apply(
+                grads, opt_state, params,
+                shardings=tree_shardings(params, mesh))
         metrics = {"loss": m["loss"], "aux": m["aux"], "ntok": m["ntok"],
                    "global_batch": gbatch}
         return new_params, new_state, metrics
