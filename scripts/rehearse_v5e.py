@@ -10,6 +10,8 @@ the chip's HBM, an unaligned tile) it refuses here, before a chip is used.
   python scripts/rehearse_v5e.py --workers 1 --layers 12 --seq 256 --bpw 8
   python scripts/rehearse_v5e.py --workers 4 --layers 1 --seq 128 --bpw 2 \\
       --consensus gossip
+  python scripts/rehearse_v5e.py --workers 1 --layers 12 --seq 1024 --bpw 3 \\
+      --hlo step.hlo.txt
 
 Runs on the CPU host (``JAX_PLATFORMS=cpu``); the compile seconds are host
 timings of the TPU compiler, never a chip time.
@@ -51,6 +53,9 @@ def main(argv=None) -> int:
     ap.add_argument("--consensus", default="exact",
                     choices=("exact", "gossip"))
     ap.add_argument("--kernels", default="pallas", choices=("pallas", "ref"))
+    ap.add_argument("--hlo", metavar="PATH",
+                    help="write the compiled step's HLO text here: its op "
+                         "names are the device ops of a profiler trace")
     args = ap.parse_args(argv)
     router.set_mode(args.kernels)
 
@@ -88,6 +93,9 @@ def main(argv=None) -> int:
         compiled = jax.jit(proto.step, donate_argnums=0).lower(
             state, {"tokens": tok, "labels": tok}, b).compile()
         seconds = time.perf_counter() - t0
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(compiled.as_text())
     ma = compiled.memory_analysis()
     gib = 2.0 ** 30
     peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes

@@ -1,10 +1,14 @@
-"""Pallas TPU kernel: fused dual-averaging prox  w = w0 - z / (2 beta).
+"""Pallas TPU kernels for the dual-averaging update phase (paper eq. 7).
 
-The paper's update phase (eq. 7) applied to every parameter each epoch.  It
-is purely memory-bound (2 reads + 1 write per element); fusing the subtract,
-scale, and dtype cast into one VMEM pass avoids materialising z/(2beta) in
-HBM, which matters because z is fp32 and model-sized (the dominant optimizer
-traffic term in §Roofline for train_4k).
+  * :func:`dual_update_step_pallas` — the whole update of a leaf in one
+    pass: ``z += g`` and the prox ``w = w0 - z / (2 beta)`` cast to the
+    parameter dtype, on the leaf in its own layout (no flatten, pad or cast
+    copies), with ``z`` updated in place.  Reads z, g, w0 and writes z, w
+    once: 16 B an element at bf16 parameters.
+  * :func:`dual_update_pallas` — the prox alone over a flattened leaf
+    (``ops.dual_update``), which the tests hold the fused step to.
+
+Both are purely memory-bound; z is fp32 and model-sized.
 """
 from __future__ import annotations
 
@@ -18,6 +22,11 @@ Array = jax.Array
 
 LANE = 128
 DEFAULT_BLOCK = 1024 * LANE      # elements per VMEM tile (512 KiB fp32)
+SUBLANE = 16                     # rows of a bf16 (16, 128) tile
+STEP_BLOCK = 256 * 1024          # VMEM elements per fused-step block, lane
+                                 # padding included: 16 B an element at bf16
+                                 # params and g, 20 B at f32; double-buffered
+                                 # that is 8-10 MiB of v5e's 16 MiB scoped VMEM
 
 
 def _kernel(z_ref, w0_ref, beta_ref, o_ref):
@@ -66,3 +75,65 @@ def dual_update_pallas(z: Array, w0: Array, beta: Array, *,
         interpret=interpret,
     )(z2, w2, beta2)
     return out.reshape(-1)[:n].reshape(shape)
+
+
+def _step(z, g, w0, beta, out_dtype):
+    """The fused step's f32 arithmetic: (z + g, the prox in ``out_dtype``)."""
+    z = z + g.astype(jnp.float32)
+    w = w0.astype(jnp.float32) - z * (0.5 / beta)
+    return z, w.astype(out_dtype)
+
+
+def _step_kernel(z_ref, g_ref, w0_ref, beta_ref, z_out_ref, w_ref):
+    z_out_ref[...], w_ref[...] = _step(z_ref[...], g_ref[...], w0_ref[...],
+                                       beta_ref[0, 0], w_ref.dtype)
+
+
+def _step_tile(rows: int, cols: int, block: int) -> tuple[int, int]:
+    """A (r, c) block that takes about ``block`` elements of VMEM for a
+    (rows, cols) view, where a row is padded to whole 128-lane tiles: whole
+    rows where 16 of them fit (contiguous in HBM), else 16 rows of
+    lane-aligned columns; a dimension the block covers is taken whole."""
+    lanes = -(-cols // LANE) * LANE
+    if lanes * SUBLANE <= block:
+        return min(block // lanes // SUBLANE * SUBLANE, rows), cols
+    c = max(block // SUBLANE // LANE * LANE, LANE)
+    return min(SUBLANE, rows), min(c, cols)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("out_dtype", "block", "interpret"))
+def dual_update_step_pallas(z: Array, g: Array, w0: Array, beta: Array, *,
+                            out_dtype, block: int = STEP_BLOCK,
+                            interpret: bool = False) -> tuple[Array, Array]:
+    """z_new = z + g (f32), w = (w0 - z_new / (2 beta)) in ``out_dtype``.
+
+    z, w0: f32 of one shape; g: that shape, any float dtype; beta: scalar.
+    A leaf of rank >= 2 is viewed as (prod(shape[:-1]), shape[-1]), a 1-D
+    leaf as (1, n), and tiled by a 2-D grid whose edge blocks may be
+    partial.  z is aliased to z_new: a donated z is updated in place.
+
+    A leaf of rank >= 2 whose last dimension is not whole lanes (an MoE
+    router's experts) is one XLA fusion of the same arithmetic instead:
+    the TPU lays such a leaf out with its two minor dimensions swapped
+    where that pads less, and a Mosaic kernel takes only row-major
+    operands, so the kernel would copy each one in and out.
+    """
+    shape = z.shape
+    if z.ndim >= 2 and shape[-1] % LANE:
+        return _step(z, g, w0, beta.astype(jnp.float32), out_dtype)
+    view = (1, z.size) if z.ndim < 2 else (z.size // shape[-1], shape[-1])
+    r, c = _step_tile(*view, block)
+    tile = pl.BlockSpec((r, c), lambda i, j: (i, j))
+    z_new, w = pl.pallas_call(
+        _step_kernel,
+        grid=(pl.cdiv(view[0], r), pl.cdiv(view[1], c)),
+        in_specs=[tile, tile, tile, pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
+        out_specs=(tile, tile),
+        out_shape=(jax.ShapeDtypeStruct(view, jnp.float32),
+                   jax.ShapeDtypeStruct(view, out_dtype)),
+        input_output_aliases={0: 0},
+        interpret=interpret,
+    )(z.reshape(view), g.reshape(view), w0.reshape(view),
+      jnp.reshape(beta.astype(jnp.float32), (1, 1)))
+    return z_new.reshape(shape), w.reshape(shape)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import ref, router
-from .dual_update import dual_update_pallas
+from .dual_update import dual_update_pallas, dual_update_step_pallas
 from .flash_attention import flash_attention_pallas
 from .gossip_combine import (gossip_combine_pallas, quantized_combine_pallas,
                              stochastic_quantize_pallas)
@@ -65,12 +65,34 @@ def dual_update(z: Array, w0: Array, beta: Array,
             lambda z, w0, beta: dual_update_pallas(z, w0, beta,
                                                    interpret=interpret),
             sharding, (spec, spec, P()), spec)(z, w0, beta)
-    if radius is not None:
-        delta = w - w0.astype(jnp.float32)
-        nrm = jnp.linalg.norm(delta.reshape(-1))
-        w = w0.astype(jnp.float32) + delta * jnp.minimum(
-            1.0, radius / jnp.maximum(nrm, 1e-30))
-    return w
+    return w if radius is None else project(w, w0, radius)
+
+
+def project(w: Array, w0: Array, radius: float) -> Array:
+    """f32 ``w`` projected onto the ball ||w - w0|| <= radius."""
+    delta = w - w0.astype(jnp.float32)
+    nrm = jnp.linalg.norm(delta.reshape(-1))
+    return w0.astype(jnp.float32) + delta * jnp.minimum(
+        1.0, radius / jnp.maximum(nrm, 1e-30))
+
+
+def dual_step(z: Array, g: Array, w0: Array, beta: Array, out_dtype,
+              force: Optional[str] = None,
+              sharding: Optional[NamedSharding] = None):
+    """One fused dual-averaging step of a leaf: (z + g, its prox in
+    ``out_dtype``), with z updated in place where it is donated.
+
+    ``sharding``: the layout of ``z``, ``g`` and ``w0``.
+    """
+    impl = router.resolve(force)
+    if impl == "ref":
+        return ref.dual_step_ref(z, g, w0, beta, out_dtype)
+    interpret = impl == "pallas_interpret"
+    spec = _spec(sharding)
+    return _per_device(
+        lambda z, g, w0, beta: dual_update_step_pallas(
+            z, g, w0, beta, out_dtype=out_dtype, interpret=interpret),
+        sharding, (spec, spec, spec, P()), (spec, spec))(z, g, w0, beta)
 
 
 def gossip_combine(msgs, weights: Array, force: Optional[str] = None,

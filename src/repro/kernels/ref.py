@@ -18,6 +18,13 @@ def dual_update_ref(z: Array, w0: Array, beta: Array) -> Array:
             - z.astype(jnp.float32) / (2.0 * beta.astype(jnp.float32)))
 
 
+def dual_step_ref(z: Array, g: Array, w0: Array, beta: Array, out_dtype):
+    """One dual-averaging step: z_new = z + g, w = the prox of z_new in
+    ``out_dtype``.  fp32 math.  Returns (z_new, w)."""
+    z_new = z + g.astype(jnp.float32)
+    return z_new, dual_update_ref(z_new, w0, beta).astype(out_dtype)
+
+
 def gossip_combine_ref(msgs, weights: Array) -> Array:
     """Weighted neighbor combine: out = sum_k weights[k] * msgs[k].
 

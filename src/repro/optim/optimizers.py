@@ -7,8 +7,8 @@ eq. 2's ``w(1) = argmin h``), giving the closed-form prox
     w(t+1) = w(1) - z(t+1) / (2 beta(t+1)).
 
 For convex problems with ``w(1) = 0`` this is exactly the paper's update.
-The prox is fused into a single Pallas kernel on TPU
-(``repro.kernels.ops.dual_update``); here it routes through the same op.
+On TPU the dual step ``z += g`` and the prox run as one Pallas kernel per
+leaf (``repro.kernels.ops.dual_step``); a ``radius`` projection follows it.
 """
 from __future__ import annotations
 
@@ -52,15 +52,20 @@ class DualAveragingOpt(Optimizer):
         from ..kernels import ops as kops
         t_new = state["t"] + 1
         beta = self.beta(t_new.astype(jnp.float32) + 1.0)
-        z_new = jax.tree.map(
-            lambda z, g: z + g.astype(jnp.float32), state["z"], grads)
+        shard = () if shardings is None else (shardings,)
 
-        def prox(z, w0, p, sharding=None):
-            w = kops.dual_update(z, w0, beta, self.radius, sharding=sharding)
-            return w.astype(p.dtype)
-        trees = (z_new, state["w0"], params)
-        new_params = jax.tree.map(
-            prox, *trees, *(() if shardings is None else (shardings,)))
+        # z += g and the prox in one pass per leaf, z updated in place; a
+        # projection needs the whole step's norm, so w stays f32 until it
+        def step(z, g, w0, p, sharding=None):
+            dtype = p.dtype if self.radius is None else jnp.float32
+            z, w = kops.dual_step(z, g, w0, beta, dtype, sharding=sharding)
+            if self.radius is not None:
+                w = kops.project(w, w0, self.radius).astype(p.dtype)
+            return z, w
+        out = jax.tree.map(step, state["z"], grads, state["w0"], params,
+                           *shard)
+        z_new, new_params = jax.tree.transpose(
+            jax.tree.structure(params), jax.tree.structure((0, 0)), out)
         return new_params, {"z": z_new, "w0": state["w0"], "t": t_new}
 
 

@@ -5,13 +5,17 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
-from repro.kernels.dual_update import dual_update_pallas
+from repro.core.dual_averaging import BetaSchedule
+from repro.kernels import ops, ref, router
+from repro.kernels.dual_update import (LANE, STEP_BLOCK, SUBLANE,
+                                       _step_tile, dual_update_pallas,
+                                       dual_update_step_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.gossip_combine import (gossip_combine_pallas,
                                           quantized_combine_pallas,
                                           stochastic_quantize_pallas)
 from repro.kernels.rwkv6_scan import rwkv6_scan_pallas
+from repro.optim import DualAveragingOpt
 
 
 # ---------------------------------------------------------------------------
@@ -36,6 +40,122 @@ def test_dual_update_op_with_radius():
     w0 = jnp.zeros((16,))
     w = ops.dual_update(z, w0, jnp.float32(1.0), radius=1.0, force="ref")
     assert abs(float(jnp.linalg.norm(w)) - 1.0) < 1e-5
+
+
+def _bits(x):
+    return np.asarray(x.astype(jnp.float32)).view(np.uint32)
+
+
+# (shape, block): partial edge blocks in both dimensions (16-row blocks of
+# 1,024 and 128 columns), a leaf under one block, a 1-D leaf, a 3-D leaf,
+# a leaf narrower than a lane tile (16-row blocks, a partial last one)
+@pytest.mark.parametrize("shape,block", [((40, 3000), 16384),
+                                         ((100, 300), 2048),
+                                         ((12, 256), STEP_BLOCK),
+                                         ((7,), STEP_BLOCK),
+                                         ((3, 16, 129), 2048),
+                                         ((300, 16), 2048)],
+                         ids=["edges", "edges_narrow", "small", "1d", "3d",
+                              "narrow"])
+@pytest.mark.parametrize("gdtype", [jnp.float32, jnp.bfloat16],
+                         ids=["g_f32", "g_bf16"])
+@pytest.mark.parametrize("pdtype", [jnp.float32, jnp.bfloat16],
+                         ids=["p_f32", "p_bf16"])
+def test_dual_step_matches_two_pass_update(shape, block, gdtype, pdtype):
+    """The fused step is the add, the prox kernel and the cast, bit for bit."""
+    k = jax.random.PRNGKey(0)
+    z = jax.random.normal(k, shape, jnp.float32)
+    g = jax.random.normal(jax.random.fold_in(k, 1), shape, gdtype)
+    w0 = jax.random.normal(jax.random.fold_in(k, 2), shape, jnp.float32)
+    beta = jnp.float32(1.7)
+    z_new, w = dual_update_step_pallas(z, g, w0, beta, out_dtype=pdtype,
+                                       block=block, interpret=True)
+    z_old = z + g.astype(jnp.float32)
+    w_old = dual_update_pallas(z_old, w0, beta, interpret=True).astype(pdtype)
+    assert z_new.dtype == jnp.float32 and w.dtype == pdtype
+    assert z_new.shape == w.shape == shape
+    np.testing.assert_array_equal(_bits(z_new), _bits(z_old))
+    np.testing.assert_array_equal(_bits(w), _bits(w_old))
+
+
+@pytest.mark.parametrize("view", [(131072, 16), (1, 1536), (12, 256),
+                                  (151936, 1536), (1536, 151936),
+                                  (10, 100), (1, 10_000_000)])
+def test_step_tile_fits_block_in_vmem(view):
+    """A fused-step block, its rows and lanes padded to whole (16, 128)
+    tiles as VMEM holds them, takes at most ``STEP_BLOCK`` elements, and
+    each side is tile-aligned or the whole dimension."""
+    rows, cols = view
+    r, c = _step_tile(rows, cols, STEP_BLOCK)
+    assert r % SUBLANE == 0 or r == rows
+    assert c % LANE == 0 or c == cols
+    assert -(-r // SUBLANE) * SUBLANE * -(-c // LANE) * LANE <= STEP_BLOCK
+
+
+def _small_tree():
+    k = jax.random.PRNGKey(3)
+    params = {"embed": jax.random.normal(k, (40, 24), jnp.bfloat16),
+              "blocks": {"w": jax.random.normal(jax.random.fold_in(k, 1),
+                                                (2, 24, 136), jnp.bfloat16),
+                         "norm": jnp.ones((2, 24), jnp.bfloat16)},
+              "head_b": jnp.zeros((9,), jnp.float32)}
+    leaves, tree = jax.tree.flatten(params)
+    grads = tree.unflatten([
+        0.3 * jax.random.normal(jax.random.fold_in(k, 10 + i), p.shape,
+                                p.dtype) for i, p in enumerate(leaves)])
+    return params, grads
+
+
+@pytest.mark.parametrize("route", ["ref", "pallas_interpret"])
+@pytest.mark.parametrize("radius", [None, 1.0], ids=["fused", "radius"])
+def test_dual_averaging_apply_matches_two_pass_update(radius, route):
+    """apply over a tree, two steps: the fused path (radius None) and the
+    kept radius path both equal z += g, then the prox op, then the cast."""
+    params, grads = _small_tree()
+    opt = DualAveragingOpt(beta=BetaSchedule(k=10.0, mu=1.0, scale=10.0),
+                           radius=radius)
+    router.set_mode(route)
+    try:
+        state = opt.init(params)
+        new_params, new_state = params, state
+        for _ in range(2):
+            z, w0 = new_state["z"], new_state["w0"]
+            beta = opt.beta((new_state["t"] + 1).astype(jnp.float32) + 1.0)
+            want_z = jax.tree.map(lambda z, g: z + g.astype(jnp.float32),
+                                  z, grads)
+            want_p = jax.tree.map(
+                lambda z, w0, p: ops.dual_update(
+                    z, w0, beta, radius, force=route).astype(p.dtype),
+                want_z, w0, new_params)
+            new_params, new_state = jax.jit(opt.apply)(
+                grads, new_state, new_params)
+            for got, want in ((new_state["z"], want_z),
+                              (new_params, want_p)):
+                assert (jax.tree.structure(got)
+                        == jax.tree.structure(want))
+                for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                    assert a.dtype == b.dtype
+                    np.testing.assert_array_equal(_bits(a), _bits(b))
+        assert int(new_state["t"]) == 2
+    finally:
+        router.set_mode(None)
+
+
+def test_prox_fault_still_leaves_params_unchanged(monkeypatch):
+    """The benchmark's ``prox`` fault wraps apply: z moves, params do not."""
+    from pathlib import Path
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    monkeypatch.setattr(DualAveragingOpt, "apply", DualAveragingOpt.apply)
+    from bench import faults
+    faults.plant("prox")
+    params, grads = _small_tree()
+    opt = DualAveragingOpt()
+    state = opt.init(params)
+    new_params, new_state = opt.apply(grads, state, params)
+    for a, b in zip(jax.tree.leaves(new_params), jax.tree.leaves(params)):
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    for z, g in zip(jax.tree.leaves(new_state["z"]), jax.tree.leaves(grads)):
+        np.testing.assert_array_equal(_bits(z), _bits(g.astype(jnp.float32)))
 
 
 # ---------------------------------------------------------------------------

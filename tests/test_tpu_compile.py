@@ -10,6 +10,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers
 import every test file.
 """
+import math
 import os
 
 import jax
@@ -21,8 +22,13 @@ from repro.kernels import ops
 
 # qwen2-1.5b leaves the dual-averaging prox visits (published widths)
 EMBED = (151936, 1536)          # embed / unembed
+UNEMBED = (1536, 151936)        # the output head, (d_model, vocabulary)
+MLP = (12, 1536, 8960)          # gate / up stacked over 12 layers
 NORM = (1536,)                  # final_norm, ln1/ln2 rows
 KV_BIAS = (12, 256)             # bk / bv stacked over 12 layers
+# phi3.5-moe's f32 router (d_model, experts) stacked over 32 layers: a
+# narrow leaf, which the TPU lays out with its minor dimensions swapped
+ROUTER = (32, 4096, 16)
 # one worker's gossip row on the four-chip mesh: every parameter of the
 # one-layer cut plus the eq.-6 weight column, padded to whole f32 tiles
 PAYLOAD = 513546752 + 1 + 511
@@ -53,11 +59,11 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, donate=(), kernel=True):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
     return compiled
 
 
@@ -68,6 +74,33 @@ def test_dual_update_compiles(one_chip, shape):
                                                  force="pallas"),
              one_chip, (shape, jnp.float32), (shape, jnp.float32),
              ((), jnp.float32))
+
+
+@pytest.mark.parametrize("shape,dtype",
+                         [(EMBED, jnp.bfloat16), (UNEMBED, jnp.bfloat16),
+                          (MLP, jnp.bfloat16), (KV_BIAS, jnp.bfloat16),
+                          (NORM, jnp.bfloat16), (ROUTER, jnp.float32)],
+                         ids=["embed", "unembed", "mlp", "kv_bias", "norm",
+                              "router"])
+def test_dual_step_compiles_in_place(one_chip, shape, dtype):
+    """The fused step is one pass on the leaf as it lies: no pad, no
+    relayout of a leaf of rank >= 2, no temporaries, z updated in place
+    (``dtype``: of g and the params).  It is one kernel, but on a leaf
+    whose last dimension is not whole lanes, one XLA fusion."""
+    f32 = (shape, jnp.float32)
+    kernel = shape[-1] % 128 == 0
+    compiled = _compile(lambda z, g, w0, beta: ops.dual_step(
+        z, g, w0, beta, dtype, force="pallas"),
+        one_chip, f32, (shape, dtype), f32, ((), jnp.float32),
+        donate=(0,), kernel=kernel)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernel
+    assert "pad(" not in text
+    if len(shape) >= 2:
+        assert "reshape(" not in text
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 2 ** 20
+    assert ma.alias_size_in_bytes >= 4 * math.prod(shape)
 
 
 @pytest.mark.parametrize("n", [PAYLOAD, 999], ids=["payload", "short"])
